@@ -107,6 +107,8 @@ def main():
                     help="persist the autotune section's cache here")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import serving_throughput
 
     t0 = time.time()

@@ -741,7 +741,11 @@ def mesh_report(quiet=False, timeout=1800):
     here = os.path.abspath(__file__)
     root = os.path.dirname(os.path.dirname(here))
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # a simulated 8-device CPU mesh: the child never touches a chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8").strip()
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))

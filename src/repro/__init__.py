@@ -1,10 +1,3 @@
-"""BLAST reproduction package.
-
-Importing the package installs the JAX API compat shims (see ``compat.py``)
-so all entry points — launchers, tests, subprocess dry-runs — see the same
-mesh/AxisType surface regardless of the pinned jax version.
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""BLAST reproduction package: structured linears (``core``), Pallas TPU
+kernels (``kernels``), models, the serving engine (``serve``) and the
+launchers (``launch``)."""

@@ -6,7 +6,7 @@
 - ``flash_attention``       causal / sliding-window / GQA online-softmax attn
 - ``autotune``              measured (block_t, block_r) cache per call shape
 - ``ref``                   pure-jnp oracles (the correctness contract)
-- ``ops``                   jit'd wrappers: padding, block sizing, interpret
+- ``ops``                   jit'd wrappers: padding, block sizing, where kernels run
 
 Decode note: at T <= block_t the fused BLAST kernel runs a single T-tile, so
 every factor (U, S, V) streams from HBM exactly once -- bandwidth-optimal

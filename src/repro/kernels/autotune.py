@@ -156,33 +156,32 @@ def candidates(T: int, m: int, n: int, b: int, r: int,
     """VMEM-feasible (block_t, block_r) configs worth timing.
 
     The sweep is the heuristic's own search lattice (block_t halvings ×
-    {128, 64, 32} r-tiles) clamped to the call's actual (rounded-up) T and
-    r — a handful of configs, always including the heuristic's pick.
+    legal r-tiles, ``ops._legal_block_r``) clamped to the call's actual
+    (rounded-up) T and r — a handful of configs, always including the
+    heuristic's pick.
     """
     from repro.kernels import ops  # local: ops imports this module
+    from repro.kernels.blast_matmul import VMEM_BUDGET, vmem_bytes
 
-    t_cap = min(128, ops._round_up(T, 8))
-    r_cap = min(128, ops._round_up(r, 8))
     fb = bytes_per_el if factor_bytes is None else factor_bytes
-    p, q = m // b, n // b
+    packed = fb < 1
+    lane = ops._rank_lane(packed)
+    t_cap = min(128, ops._round_up(T, 8))
     out: list[tuple[int, int]] = []
     bt = t_cap
     while bt >= 8:
-        for br in (128, 64, 32):
-            br = min(br, r_cap)
-            resident = (
-                bt * n * bytes_per_el
-                + b * bt * br * 4
-                + bt * m * 4
-                + int((p * br + b * b * br + b * q * br) * fb)
-            )
-            if resident <= ops._VMEM_BUDGET and (bt, br) not in out:
+        for br in (4 * lane, 2 * lane, lane):
+            br = ops._legal_block_r(min(br, ops._round_up(r, lane)), r,
+                                    packed)
+            if (vmem_bytes(bt, br, m, n, b, bytes_per_el, fb,
+                           bytes_per_el) <= VMEM_BUDGET
+                    and (bt, br) not in out):
                 out.append((bt, br))
         if bt == 8:
             break
         bt = max(bt // 2, 8)
     heur = ops.pick_blast_blocks(T, m, n, b, r, bytes_per_el, factor_bytes)
-    heur = (min(heur[0], t_cap), min(heur[1], r_cap))
+    heur = (min(heur[0], t_cap), ops._legal_block_r(heur[1], r, packed))
     if heur not in out:
         out.append(heur)
     return out

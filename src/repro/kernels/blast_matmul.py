@@ -10,16 +10,25 @@ TPU adaptation: one fused kernel.  Grid = ``(T_tiles, r_tiles, b_i)``:
     per (T, r) tile, amortized over all b output blocks;
   * each i does the VPU coupling reduce ``w_i = Σ_j s_ij ⊙ z_j`` and the MXU
     projection ``y_i += w_i @ U_iᵀ``, accumulated in a fp32 VMEM scratch
-    ``(T_t, m)`` that is flushed to HBM once per T tile.
+    ``(b, T_t, p)`` that is flushed to HBM once per T tile.
 
 Z and W therefore never touch HBM; the only HBM traffic is X, U/S/V (once
-per T tile) and Y (once).  Block shapes are chosen in ``ops.py`` so the
-resident set (x-tile + z-scratch + y-accumulator + factor tiles) fits a
-16 MB v5e VMEM, with MXU-aligned (multiple-of-128) r/T tiles when possible.
+per T tile) and Y (once).
 
-Variants (all share the ``_stages`` scaffold — the three compute stages,
-accumulator init and flush are written once, parameterized by factor
-loaders / per-stage dequant scalers):
+Block layout: the kernels see x as ``(b, T, q)`` and write y as
+``(b, T, p)`` — the wrappers below regroup ``(T, n)`` / ``(T, m)`` around
+the ``pallas_call``.  Every per-block access in the kernel body is then an
+index on a leading (untiled) axis: ``x_ref[j]``, ``s_ref[i]``, ``y_scr[i]``.
+That is what the TPU compiler needs for any block width: a column slice at
+``i·p`` lands on a lane offset that is a multiple of 128 only when p is,
+and neither Llama-7B's FFN (p = 11008/16 = 688) nor SmolLM (p = 36) is.
+Block shapes (``block_t`` rows, ``block_r`` ranks) are chosen in ``ops.py``
+so the resident set (``vmem_bytes``) fits the scoped VMEM limit.
+
+Variants (all share the ``_stages`` scaffold and the ``_blast_call``
+wrapper — the three compute stages, accumulator init and flush, grid and
+block specs are written once, parameterized by factor loaders / per-stage
+dequant scalers):
 
   * ``blast_matmul_pallas``             float factors
   * ``blast_matmul_q_pallas``           int8-code factors, per-block scales
@@ -68,6 +77,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# The compiler's default scoped-VMEM limit on v5e.  ``ops.pick_blast_blocks``
+# keeps ``vmem_bytes`` under ``VMEM_BUDGET``; a call whose smallest legal
+# tiling still exceeds it (fp32 factors at wide q) raises the limit instead.
+_DEFAULT_VMEM_LIMIT = 16 * 1024 * 1024
+VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _lanes(d: int) -> int:
+    return -(-d // 128) * 128
+
+
+def vmem_bytes(block_t: int, block_r: int, m: int, n: int, b: int,
+               x_bytes: float, factor_bytes: float,
+               out_bytes: float = 4) -> int:
+    """Resident VMEM of one grid step: double-buffered x / U / S / V / out
+    blocks plus the fp32 z and y scratch and the stage-2 product temporary.
+    Lane (last) dims are counted padded to 128, as the TPU stores them."""
+    p, q = m // b, n // b
+    blocks = (b * block_t * _lanes(q) * x_bytes
+              + (p + b * b + b * q) * block_r * factor_bytes
+              + b * block_t * _lanes(p) * out_bytes)
+    scratch = 2 * b * block_t * block_r * 4 + b * block_t * _lanes(p) * 4
+    return int(2 * blocks + scratch)
+
 
 def _unpack_nibbles(packed: jax.Array) -> jax.Array:
     """uint8 nibble pairs (..., P) → int32 codes (..., 2P) in plane order
@@ -102,16 +135,14 @@ def _stages(x_ref, out_ref, z_scr, y_scr, *, b, n_r_tiles, rt_axis,
     """
     rt = pl.program_id(rt_axis)
     i = pl.program_id(rt_axis + 1)
-    q = x_ref.shape[1] // b
 
     # ---- stage 1 (once per (T, r) tile): z_j = x_j @ V_j
     @pl.when(i == 0)
     def _compute_z():
-        x = x_ref[...]
         for j in range(b):  # b is static and small (≤16): unrolled
-            xj = x[:, j * q:(j + 1) * q]
+            xj = x_ref[j]                                 # (T_t, q)
             zj = jax.lax.dot_general(
-                xj, load_v(j, x.dtype), (((1,), (0,)), ((), ())),
+                xj, load_v(j, xj.dtype), (((1,), (0,)), ((), ())),
                 preferred_element_type=acc1,
             )
             z_scr[j] = scale_z(zj, j)
@@ -125,12 +156,10 @@ def _stages(x_ref, out_ref, z_scr, y_scr, *, b, n_r_tiles, rt_axis,
     w = jnp.sum(s_i[:, None, :] * z_scr[...], axis=0)     # (T_t, r_t)
 
     # ---- stage 3 (MXU): y_i += w @ U_iᵀ, accumulated over r tiles
-    u_i = load_u()                                        # (p, r_t)
     y_part = jax.lax.dot_general(
-        w, u_i, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    p = u_i.shape[0]
-    col = i * p
-    y_scr[:, pl.ds(col, p)] = y_scr[:, pl.ds(col, p)] + scale_y(y_part, i)
+        w, load_u(), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)               # (T_t, p)
+    y_scr[i] = y_scr[i] + scale_y(y_part, i)
 
     # ---- flush once per T tile
     @pl.when((rt == n_r_tiles - 1) & (i == b - 1))
@@ -138,199 +167,180 @@ def _stages(x_ref, out_ref, z_scr, y_scr, *, b, n_r_tiles, rt_axis,
         out_ref[...] = y_scr[...].reshape(out_ref.shape).astype(out_ref.dtype)
 
 
-def _float_loaders(u_ref, s_ref, v_ref):
-    """Factor accessors for float kernels; handles the grouped variants'
-    extra leading unit block dim by indexing it away."""
-    gl = s_ref.ndim - 3  # 0 ungrouped, 1 grouped
-    s3 = s_ref[0] if gl else s_ref[...]
-    return dict(
-        load_v=lambda j, dt: v_ref[(0,) * gl + (j,)],
-        load_s=lambda i: jax.lax.dynamic_index_in_dim(
-            s3, i, 0, keepdims=False).astype(jnp.float32),
-        load_u=lambda: u_ref[(0,) * (u_ref.ndim - 2)],
-        scale_z=lambda z, j: z,
-        scale_y=lambda y, i: y,
-    )
+def _loaders(u_ref, s_ref, v_ref, su_ref, ss_ref, sv_ref, sx_ref, *, g,
+             packed):
+    """Factor accessors.  ``lead`` indexes away the grouped variants' unit
+    factor-set block dim; per-block reads index the ref itself (``s_ref[i]``),
+    never a loaded value, so the index stays a scalar address offset.
 
-
-def _quant_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref, sv_ref, *,
-                   g=None, packed=False):
-    """Factor accessors for the int8/int4 kernels: U/S/V tiles arrive in
-    VMEM as integer codes (the whole point — half/quarter the HBM traffic),
-    are cast (int4: unpacked) in-register for the MXU/VPU ops, and each
-    stage's per-block scale multiplies the stage *output* — quantized
-    factors never round-trip through HBM as floats.
-
-    ``su``/``sv`` are scalar-prefetched into SMEM (scalar reads per block
-    index); ``ss`` rides as a tiny fp32 VMEM operand ``(b, b, 1)`` so the
-    per-row read ``ss[i]`` is a single vectorized load, not b scalar picks.
-    ``g`` indexes the grouped variants' leading factor-set axis.
+    Float kernels (no scales): tiles feed the MXU/VPU as stored.  Quantized
+    kernels: U/S/V tiles arrive in VMEM as integer codes (int4: nibble-packed
+    bytes), are cast (unpacked) in-register, and each stage's per-block
+    scale multiplies the stage *output*.  ``su``/``sv`` are scalar-prefetched
+    into SMEM; ``ss`` rides as a fp32 VMEM operand ``(b, b, 1)`` so the row
+    read ``ss[i]`` is one vectorized load.  Integer-activation kernels
+    (``sx_ref`` given) hand stage 1 raw int8 V codes (int4 sign-extends to
+    int8) and fuse the activation and factor dequant into one multiply of
+    the int32 tile: ``z · (sx ⊗ sv_j)``.
     """
-    gl = s_ref.ndim - 3
-    s3 = s_ref[0] if gl else s_ref[...]
-    ss3 = ss_ref[0] if ss_ref.ndim == 4 else ss_ref[...]   # (b, b, 1) fp32
+    lead = (0,) * (s_ref.ndim - 3)
+    if su_ref is None:
+        return dict(
+            load_v=lambda j, dt: v_ref[lead + (j,)],
+            load_s=lambda i: s_ref[lead + (i,)].astype(jnp.float32),
+            load_u=lambda: u_ref[lead + (0,)],
+            scale_z=lambda z, j: z,
+            scale_y=lambda y, i: y,
+        )
     unpack = _unpack_nibbles if packed else (lambda t: t)
     su = (lambda i: su_ref[g, i]) if g is not None else (lambda i: su_ref[i])
     sv = (lambda j: sv_ref[g, j]) if g is not None else (lambda j: sv_ref[j])
 
     def load_s(i):
-        codes = jax.lax.dynamic_index_in_dim(s3, i, 0, keepdims=False)
-        ss_i = jax.lax.dynamic_index_in_dim(ss3, i, 0, keepdims=False)
-        return unpack(codes).astype(jnp.float32) * ss_i    # (b, r_t)·(b, 1)
+        codes = unpack(s_ref[lead + (i,)]).astype(jnp.float32)   # (b, r_t)
+        return codes * ss_ref[lead + (i,)]                        # · (b, 1)
 
+    if sx_ref is None:
+        load_v = lambda j, dt: unpack(v_ref[lead + (j,)]).astype(dt)  # noqa: E731
+        scale_z = lambda z, j: z * sv(j)                               # noqa: E731
+    else:
+        load_v = lambda j, dt: unpack(v_ref[lead + (j,)]).astype(jnp.int8)  # noqa: E731
+        scale_z = lambda z, j: z.astype(jnp.float32) * (sx_ref[...] * sv(j))  # noqa: E731
     return dict(
-        load_v=lambda j, dt: unpack(v_ref[(0,) * gl + (j,)]).astype(dt),
+        load_v=load_v,
         load_s=load_s,
-        load_u=lambda: unpack(
-            u_ref[(0,) * (u_ref.ndim - 2)]).astype(jnp.float32),
-        scale_z=lambda z, j: z * sv(j),
+        load_u=lambda: unpack(u_ref[lead + (0,)]).astype(jnp.float32),
+        scale_z=scale_z,
         scale_y=lambda y, i: y * su(i),
     )
 
 
-def _quant_act_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref, sv_ref, sx_ref,
-                       *, g=None, packed=False):
-    """Loaders for the integer-activation (W8A8 / W4A8) kernels.
-
-    ``x_ref`` holds int8 per-token codes, so ``load_v`` hands stage 1 raw
-    int8 V codes (int4: nibble-unpacked then narrowed back to int8 — values
-    live in [-8, 7]) and the stage-1 dot runs int8×int8 → int32 on the MXU.
-    ``scale_z`` fuses the activation and factor dequant into ONE multiply of
-    the int32 tile: ``z · (sx ⊗ sv_j)``, with ``sx`` the fp32 per-row
-    activation scale tile ``(T_t, 1)``.  U/S stages are unchanged from
-    ``_quant_loaders`` — they consume the already-dequantized fp32 ``z``.
-    """
-    base = _quant_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref, sv_ref,
-                          g=g, packed=packed)
-    if packed:
-        load_v = lambda j, dt: _unpack_nibbles(  # noqa: E731
-            v_ref[(0,) * (s_ref.ndim - 3) + (j,)]).astype(jnp.int8)
-    else:
-        load_v = lambda j, dt: v_ref[(0,) * (s_ref.ndim - 3) + (j,)]  # noqa: E731
-    sv = ((lambda j: sv_ref[g, j]) if g is not None
-          else (lambda j: sv_ref[j]))
-    return dict(
-        base,
-        load_v=load_v,
-        scale_z=lambda z, j: z.astype(jnp.float32) * (sx_ref[...] * sv(j)),
-    )
+def _kernel(*refs, b: int, n_r_tiles: int, grouped: bool, quant: bool,
+            act: bool, packed: bool):
+    """One kernel body for every variant; the operand list is
+    ``[su, sv,] x, U, S, V, [ss,] [sx,] out, z_scr, y_scr``."""
+    refs = list(refs)
+    su_ref = sv_ref = ss_ref = sx_ref = None
+    if quant:
+        su_ref, sv_ref = refs.pop(0), refs.pop(0)
+    x_ref, u_ref, s_ref, v_ref = refs[:4]
+    rest = refs[4:]
+    if quant:
+        ss_ref = rest.pop(0)
+    if act:
+        sx_ref = rest.pop(0)
+    out_ref, z_scr, y_scr = rest
+    g = pl.program_id(0) if grouped else None
+    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
+            rt_axis=2 if grouped else 1,
+            acc1=jnp.int32 if act else jnp.float32,
+            **_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref, sv_ref, sx_ref,
+                       g=g, packed=packed))
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies (thin: bind loaders + grid-axis layout, call _stages).
+# The one pallas_call builder.
 # ---------------------------------------------------------------------------
 
 
-def _kernel(x_ref, u_ref, s_ref, v_ref, out_ref, z_scr, y_scr, *, b: int,
-            n_r_tiles: int):
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=1, **_float_loaders(u_ref, s_ref, v_ref))
+def _blast_call(x, U, S, V, scales=None, sx=None, *, packed=False,
+                block_t: int, block_r: int, interpret: bool, out_dtype=None):
+    """x (T, n); U (…, b, p, r·), S (…, b, b, r·), V (…, b, q, r·) with an
+    optional leading G (grouped) → y (T, m) or (G, T, m).  ``scales`` is
+    ``(su, ss, sv)`` for quantized factors; ``sx (T, 1)`` marks int8
+    activation codes in ``x``.  ``packed``: factors are nibble pairs along
+    r (r· = r/2).  T must be a multiple of ``block_t`` and the logical r of
+    ``block_r`` (``ops.py`` pads)."""
+    T, n = x.shape
+    grouped = U.ndim == 4
+    *gdim, b, p, r_stored = U.shape
+    q = V.shape[-2]
+    m = b * p
+    r = 2 * r_stored if packed else r_stored
+    rb = block_r // 2 if packed else block_r
+    assert n == b * q, (n, b, q)
+    assert not packed or block_r % 2 == 0, block_r
+    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
+    if sx is not None:
+        assert x.dtype == jnp.int8 and sx.shape == (T, 1), (x.dtype, sx.shape)
+    n_t, n_rt = T // block_t, r // block_r
+    out_dtype = x.dtype if out_dtype is None else out_dtype
 
+    gl = (1,) if grouped else ()
 
-def _kernel_grouped(x_ref, u_ref, s_ref, v_ref, out_ref, z_scr, y_scr, *,
-                    b: int, n_r_tiles: int):
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=2, **_float_loaders(u_ref, s_ref, v_ref))
+    def spec(shape, index):
+        """BlockSpec whose index map sees (g, t, rt, i) in every variant."""
+        if grouped:
+            return pl.BlockSpec(shape, lambda g, t, rt, i, *_: index(g, t, rt, i))
+        return pl.BlockSpec(shape, lambda t, rt, i, *_: index(None, t, rt, i))
 
+    def gi(g):
+        return (g,) if grouped else ()
 
-def _kernel_q(su_ref, sv_ref, x_ref, u_ref, s_ref, v_ref, ss_ref, out_ref,
-              z_scr, y_scr, *, b: int, n_r_tiles: int, packed: bool = False):
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=1, **_quant_loaders(u_ref, s_ref, v_ref,
-                                        su_ref, ss_ref, sv_ref,
-                                        packed=packed))
-
-
-def _kernel_grouped_q(su_ref, sv_ref, x_ref, u_ref, s_ref, v_ref, ss_ref,
-                      out_ref, z_scr, y_scr, *, b: int, n_r_tiles: int,
-                      packed: bool = False):
-    g = pl.program_id(0)
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=2, **_quant_loaders(u_ref, s_ref, v_ref,
-                                        su_ref, ss_ref, sv_ref, g=g,
-                                        packed=packed))
-
-
-def _kernel_qa(su_ref, sv_ref, x_ref, u_ref, s_ref, v_ref, ss_ref, sx_ref,
-               out_ref, z_scr, y_scr, *, b: int, n_r_tiles: int,
-               packed: bool = False):
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=1, acc1=jnp.int32,
-            **_quant_act_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref,
-                                 sv_ref, sx_ref, packed=packed))
-
-
-def _kernel_grouped_qa(su_ref, sv_ref, x_ref, u_ref, s_ref, v_ref, ss_ref,
-                       sx_ref, out_ref, z_scr, y_scr, *, b: int,
-                       n_r_tiles: int, packed: bool = False):
-    g = pl.program_id(0)
-    _stages(x_ref, out_ref, z_scr, y_scr, b=b, n_r_tiles=n_r_tiles,
-            rt_axis=2, acc1=jnp.int32,
-            **_quant_act_loaders(u_ref, s_ref, v_ref, su_ref, ss_ref,
-                                 sv_ref, sx_ref, g=g, packed=packed))
-
-
-# ---------------------------------------------------------------------------
-# pallas_call wrappers.
-# ---------------------------------------------------------------------------
-
-
-def _scratch(b, block_t, block_r, m):
-    return [
-        pltpu.VMEM((b, block_t, block_r), jnp.float32),  # z
-        pltpu.VMEM((block_t, m), jnp.float32),           # y accumulator
+    in_specs = [
+        spec((b, block_t, q), lambda g, t, rt, i: (0, t, 0)),            # x
+        spec(gl + (1, p, rb), lambda g, t, rt, i: gi(g) + (i, 0, rt)),   # U
+        spec(gl + (b, b, rb), lambda g, t, rt, i: gi(g) + (0, 0, rt)),   # S
+        spec(gl + (b, q, rb), lambda g, t, rt, i: gi(g) + (0, 0, rt)),   # V
     ]
+    xb = x.reshape(T, b, q).transpose(1, 0, 2)
+    operands = [xb, U, S, V]
+    prefetch = []
+    if scales is not None:
+        su, ss, sv = (s.astype(jnp.float32) for s in scales)
+        prefetch = [su, sv]
+        in_specs.append(spec(gl + (b, b, 1),
+                             lambda g, t, rt, i: gi(g) + (0, 0, 0)))      # ss
+        operands.append(ss.reshape(*gdim, b, b, 1))
+    if sx is not None:
+        in_specs.append(spec((block_t, 1), lambda g, t, rt, i: (t, 0)))   # sx
+        operands.append(sx.astype(jnp.float32))
+
+    est = vmem_bytes(block_t, block_r, m, n, b, x.dtype.itemsize,
+                     U.dtype.itemsize / (2 if packed else 1),
+                     jnp.dtype(out_dtype).itemsize)
+    kernel = functools.partial(
+        _kernel, b=b, n_r_tiles=n_rt, grouped=grouped,
+        quant=scales is not None, act=sx is not None, packed=packed)
+    y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(*gdim, n_t, n_rt, b),
+            in_specs=in_specs,
+            out_specs=spec(gl + (b, block_t, p),
+                           lambda g, t, rt, i: gi(g) + (0, t, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((b, block_t, block_r), jnp.float32),   # z
+                pltpu.VMEM((b, block_t, p), jnp.float32),         # y acc
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((*gdim, b, T, p), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_DEFAULT_VMEM_LIMIT, est + est // 4)),
+        interpret=interpret,
+    )(*prefetch, *operands)
+    # (…, b, T, p) → (…, T, b·p)
+    return jnp.swapaxes(y, -3, -2).reshape(*gdim, T, m)
 
 
-def blast_matmul_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
+# ---------------------------------------------------------------------------
+# Public variants.
+# ---------------------------------------------------------------------------
+
+
+def blast_matmul_pallas(x, U, S, V, *, block_t: int = 128,
+                        block_r: int = 128, interpret: bool = False):
     """x: (T, n) → (T, m).  Factors: U (b,p,r), S (b,b,r), V (b,q,r).
 
     T must be a multiple of ``block_t`` and r of ``block_r`` (ops.py pads).
     """
-    T, n = x.shape
-    b, p, r = U.shape
-    q = V.shape[1]
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-
-    kernel = functools.partial(_kernel, b=b, n_r_tiles=n_rt)
-    return pl.pallas_call(
-        kernel,
-        grid=(n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda t, rt, i: (t, 0)),           # x
-            pl.BlockSpec((1, p, block_r), lambda t, rt, i: (i, 0, rt)),    # U
-            pl.BlockSpec((b, b, block_r), lambda t, rt, i: (0, 0, rt)),    # S
-            pl.BlockSpec((b, q, block_r), lambda t, rt, i: (0, 0, rt)),    # V
-        ],
-        out_specs=pl.BlockSpec((block_t, m), lambda t, rt, i: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, m), x.dtype),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-        interpret=interpret,
-    )(x, U, S, V)
+    return _blast_call(x, U, S, V, block_t=block_t, block_r=block_r,
+                       interpret=interpret)
 
 
-def blast_matmul_grouped_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
+def blast_matmul_grouped_pallas(x, U, S, V, *, block_t: int = 128,
+                                block_r: int = 128, interpret: bool = False):
     """Grouped fused BLAST matmul: one launch for G congruent factor sets.
 
     x: (T, n) shared input; U (G,b,p,r), S (G,b,b,r), V (G,b,q,r) →
@@ -338,47 +348,12 @@ def blast_matmul_grouped_pallas(
     index ignores g, so the input tile is fetched once per (T, r) tile and
     revisited across the whole group.
     """
-    T, n = x.shape
-    G, b, p, r = U.shape
-    q = V.shape[2]
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-
-    kernel = functools.partial(_kernel_grouped, b=b, n_r_tiles=n_rt)
-    return pl.pallas_call(
-        kernel,
-        grid=(G, n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda g, t, rt, i: (t, 0)),
-            pl.BlockSpec((1, 1, p, block_r),
-                         lambda g, t, rt, i: (g, i, 0, rt)),
-            pl.BlockSpec((1, b, b, block_r),
-                         lambda g, t, rt, i: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, q, block_r),
-                         lambda g, t, rt, i: (g, 0, 0, rt)),
-        ],
-        out_specs=pl.BlockSpec((1, block_t, m), lambda g, t, rt, i: (g, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, T, m), x.dtype),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-        interpret=interpret,
-    )(x, U, S, V)
+    return _blast_call(x, U, S, V, block_t=block_t, block_r=block_r,
+                       interpret=interpret)
 
 
-def blast_matmul_q_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
+def blast_matmul_q_pallas(x, U, S, V, su, ss, sv, *, block_t: int = 128,
+                          block_r: int = 128, interpret: bool = False):
     """Fused int8 BLAST matmul.  x: (T, n) float → (T, m) float.
 
     U (b,p,r), S (b,b,r), V (b,q,r) are int8 codes; su (b,), ss (b,b),
@@ -386,50 +361,12 @@ def blast_matmul_q_pallas(
     ss as a (b, b, 1) fp32 VMEM operand (vectorized per-row reads).
     Same tiling contract as ``blast_matmul_pallas``.
     """
-    T, n = x.shape
-    b, p, r = U.shape
-    q = V.shape[1]
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-
-    kernel = functools.partial(_kernel_q, b=b, n_r_tiles=n_rt)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, p, block_r), lambda t, rt, i, *_: (i, 0, rt)),
-            pl.BlockSpec((b, b, block_r), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, q, block_r), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, b, 1), lambda t, rt, i, *_: (0, 0, 0)),   # ss
-        ],
-        out_specs=pl.BlockSpec((block_t, m), lambda t, rt, i, *_: (t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, m), x.dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      x, U, S, V, ss.astype(jnp.float32).reshape(b, b, 1))
+    return _blast_call(x, U, S, V, (su, ss, sv), block_t=block_t,
+                       block_r=block_r, interpret=interpret)
 
 
-def blast_matmul_q4_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
+def blast_matmul_q4_pallas(x, U, S, V, su, ss, sv, *, block_t: int = 128,
+                           block_r: int = 128, interpret: bool = False):
     """Fused int4 BLAST matmul over *nibble-packed* factors.
 
     U (b,p,r/2), S (b,b,r/2), V (b,q,r/2) are uint8 nibble pairs packed
@@ -438,108 +375,27 @@ def blast_matmul_q4_pallas(
     kernel's.  Logical r = 2·packed bytes must be a multiple of ``block_r``
     (even by construction); scales as in ``blast_matmul_q_pallas``.
     """
-    T, n = x.shape
-    b, p, r2 = U.shape
-    q = V.shape[1]
-    r = 2 * r2
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert block_r % 2 == 0, block_r
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-    rb = block_r // 2  # packed bytes per r tile
-
-    kernel = functools.partial(_kernel_q, b=b, n_r_tiles=n_rt, packed=True)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, p, rb), lambda t, rt, i, *_: (i, 0, rt)),
-            pl.BlockSpec((b, b, rb), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, q, rb), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, b, 1), lambda t, rt, i, *_: (0, 0, 0)),   # ss
-        ],
-        out_specs=pl.BlockSpec((block_t, m), lambda t, rt, i, *_: (t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, m), x.dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      x, U, S, V, ss.astype(jnp.float32).reshape(b, b, 1))
+    return _blast_call(x, U, S, V, (su, ss, sv), packed=True,
+                       block_t=block_t, block_r=block_r, interpret=interpret)
 
 
-def blast_matmul_grouped_q_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
+def blast_matmul_grouped_q_pallas(x, U, S, V, su, ss, sv, *,
+                                  block_t: int = 128, block_r: int = 128,
+                                  interpret: bool = False):
     """Grouped int8-factor BLAST matmul: one launch, one x-tile load.
 
     x (T, n); U (G,b,p,r), S (G,b,b,r), V (G,b,q,r) int8 codes; su (G,b),
     ss (G,b,b), sv (G,b) float scales → y (G, T, m).
     """
-    T, n = x.shape
-    G, b, p, r = U.shape
-    q = V.shape[2]
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-
-    kernel = functools.partial(_kernel_grouped_q, b=b, n_r_tiles=n_rt)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(G, n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda g, t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, 1, p, block_r),
-                         lambda g, t, rt, i, *_: (g, i, 0, rt)),
-            pl.BlockSpec((1, b, b, block_r),
-                         lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, q, block_r),
-                         lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, b, 1), lambda g, t, rt, i, *_: (g, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_t, m),
-                               lambda g, t, rt, i, *_: (g, t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, T, m), x.dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      x, U, S, V, ss.astype(jnp.float32).reshape(G, b, b, 1))
+    return _blast_call(x, U, S, V, (su, ss, sv), block_t=block_t,
+                       block_r=block_r, interpret=interpret)
 
 
-def blast_matmul_grouped_q4_pallas(
-    x: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """Grouped *nibble-packed* int4 BLAST matmul: PR 5's two wins combined —
-    one launch for G congruent factor sets AND half the factor HBM reads.
+def blast_matmul_grouped_q4_pallas(x, U, S, V, su, ss, sv, *,
+                                   block_t: int = 128, block_r: int = 128,
+                                   interpret: bool = False):
+    """Grouped *nibble-packed* int4 BLAST matmul: one launch for G congruent
+    factor sets AND half the factor HBM reads.
 
     x (T, n) float; U (G,b,p,r/2), S (G,b,b,r/2), V (G,b,q,r/2) uint8
     nibble pairs packed along r; su (G,b), ss (G,b,b), sv (G,b) float
@@ -547,104 +403,19 @@ def blast_matmul_grouped_q4_pallas(
     in-register to plane order (exact — the r contraction is
     permutation-invariant; pad bytes are zero codes).
     """
-    T, n = x.shape
-    G, b, p, r2 = U.shape
-    q = V.shape[2]
-    r = 2 * r2
-    m = b * p
-    assert n == b * q, (n, b, q)
-    assert block_r % 2 == 0, block_r
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-    rb = block_r // 2  # packed bytes per r tile
-
-    kernel = functools.partial(_kernel_grouped_q, b=b, n_r_tiles=n_rt,
-                               packed=True)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(G, n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda g, t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, 1, p, rb), lambda g, t, rt, i, *_: (g, i, 0, rt)),
-            pl.BlockSpec((1, b, b, rb), lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, q, rb), lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, b, 1), lambda g, t, rt, i, *_: (g, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_t, m),
-                               lambda g, t, rt, i, *_: (g, t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, T, m), x.dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      x, U, S, V, ss.astype(jnp.float32).reshape(G, b, b, 1))
+    return _blast_call(x, U, S, V, (su, ss, sv), packed=True,
+                       block_t=block_t, block_r=block_r, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
-# Integer-activation (W8A8 / W4A8) wrappers: x arrives as int8 per-token
+# Integer-activation (W8A8 / W4A8) variants: x arrives as int8 per-token
 # codes + fp32 per-row scales; stage 1 is an int8×int8 → int32 MXU dot.
 # ---------------------------------------------------------------------------
 
 
-def _act_call(xq, sx, U, S, V, su, ss, sv, *, packed, block_t, block_r,
-              interpret, out_dtype):
-    T, n = xq.shape
-    b, p, rU = U.shape
-    q = V.shape[1]
-    r = 2 * rU if packed else rU
-    m = b * p
-    assert xq.dtype == jnp.int8, xq.dtype
-    assert sx.shape == (T, 1), (sx.shape, T)
-    assert n == b * q, (n, b, q)
-    if packed:
-        assert block_r % 2 == 0, block_r
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-    rb = block_r // 2 if packed else block_r
-
-    kernel = functools.partial(_kernel_qa, b=b, n_r_tiles=n_rt, packed=packed)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, p, rb), lambda t, rt, i, *_: (i, 0, rt)),
-            pl.BlockSpec((b, b, rb), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, q, rb), lambda t, rt, i, *_: (0, 0, rt)),
-            pl.BlockSpec((b, b, 1), lambda t, rt, i, *_: (0, 0, 0)),    # ss
-            pl.BlockSpec((block_t, 1), lambda t, rt, i, *_: (t, 0)),    # sx
-        ],
-        out_specs=pl.BlockSpec((block_t, m), lambda t, rt, i, *_: (t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, m), out_dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      xq, U, S, V, ss.astype(jnp.float32).reshape(b, b, 1),
-      sx.astype(jnp.float32))
-
-
-def blast_matmul_w8a8_pallas(
-    xq: jax.Array,
-    sx: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
+def blast_matmul_w8a8_pallas(xq, sx, U, S, V, su, ss, sv, *,
+                             block_t: int = 128, block_r: int = 128,
+                             interpret: bool = False, out_dtype=jnp.float32):
     """Fused W8A8 BLAST matmul: int8 activation codes × int8 factor codes.
 
     xq (T, n) int8 per-token codes, sx (T, 1) fp32 per-row scales
@@ -652,116 +423,39 @@ def blast_matmul_w8a8_pallas(
     ``blast_matmul_q_pallas`` → (T, m) ``out_dtype``.  Stage 1 contracts
     raw codes in int32 (exact) and dequantizes once with ``sx · sv_j``.
     """
-    return _act_call(xq, sx, U, S, V, su, ss, sv, packed=False,
-                     block_t=block_t, block_r=block_r, interpret=interpret,
-                     out_dtype=out_dtype)
+    return _blast_call(xq, U, S, V, (su, ss, sv), sx, block_t=block_t,
+                       block_r=block_r, interpret=interpret,
+                       out_dtype=out_dtype)
 
 
-def blast_matmul_w4a8_pallas(
-    xq: jax.Array,
-    sx: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
+def blast_matmul_w4a8_pallas(xq, sx, U, S, V, su, ss, sv, *,
+                             block_t: int = 128, block_r: int = 128,
+                             interpret: bool = False, out_dtype=jnp.float32):
     """Fused W4A8 BLAST matmul: int8 activation codes × nibble-packed int4
     factors (``blast_matmul_q4_pallas`` packing; V unpacks to int8 in
     register so stage 1 stays an integer MXU dot)."""
-    return _act_call(xq, sx, U, S, V, su, ss, sv, packed=True,
-                     block_t=block_t, block_r=block_r, interpret=interpret,
-                     out_dtype=out_dtype)
+    return _blast_call(xq, U, S, V, (su, ss, sv), sx, packed=True,
+                       block_t=block_t, block_r=block_r, interpret=interpret,
+                       out_dtype=out_dtype)
 
 
-def _grouped_act_call(xq, sx, U, S, V, su, ss, sv, *, packed, block_t,
-                      block_r, interpret, out_dtype):
-    T, n = xq.shape
-    G, b, p, rU = U.shape
-    q = V.shape[2]
-    r = 2 * rU if packed else rU
-    m = b * p
-    assert xq.dtype == jnp.int8, xq.dtype
-    assert sx.shape == (T, 1), (sx.shape, T)
-    assert n == b * q, (n, b, q)
-    if packed:
-        assert block_r % 2 == 0, block_r
-    assert T % block_t == 0 and r % block_r == 0, (T, r, block_t, block_r)
-    n_t, n_rt = T // block_t, r // block_r
-    rb = block_r // 2 if packed else block_r
-
-    kernel = functools.partial(_kernel_grouped_qa, b=b, n_r_tiles=n_rt,
-                               packed=packed)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(G, n_t, n_rt, b),
-        in_specs=[
-            pl.BlockSpec((block_t, n), lambda g, t, rt, i, *_: (t, 0)),
-            pl.BlockSpec((1, 1, p, rb), lambda g, t, rt, i, *_: (g, i, 0, rt)),
-            pl.BlockSpec((1, b, b, rb), lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, q, rb), lambda g, t, rt, i, *_: (g, 0, 0, rt)),
-            pl.BlockSpec((1, b, b, 1), lambda g, t, rt, i, *_: (g, 0, 0, 0)),
-            pl.BlockSpec((block_t, 1), lambda g, t, rt, i, *_: (t, 0)),  # sx
-        ],
-        out_specs=pl.BlockSpec((1, block_t, m),
-                               lambda g, t, rt, i, *_: (g, t, 0)),
-        scratch_shapes=_scratch(b, block_t, block_r, m),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, T, m), out_dtype),
-        interpret=interpret,
-    )(su.astype(jnp.float32), sv.astype(jnp.float32),
-      xq, U, S, V, ss.astype(jnp.float32).reshape(G, b, b, 1),
-      sx.astype(jnp.float32))
-
-
-def blast_matmul_grouped_w8a8_pallas(
-    xq: jax.Array,
-    sx: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
+def blast_matmul_grouped_w8a8_pallas(xq, sx, U, S, V, su, ss, sv, *,
+                                     block_t: int = 128, block_r: int = 128,
+                                     interpret: bool = False,
+                                     out_dtype=jnp.float32):
     """Grouped W8A8: one launch for G int8 factor sets sharing one set of
     int8 activation codes (xq (T, n) int8, sx (T, 1) fp32) → (G, T, m)."""
-    return _grouped_act_call(xq, sx, U, S, V, su, ss, sv, packed=False,
-                             block_t=block_t, block_r=block_r,
-                             interpret=interpret, out_dtype=out_dtype)
+    return _blast_call(xq, U, S, V, (su, ss, sv), sx, block_t=block_t,
+                       block_r=block_r, interpret=interpret,
+                       out_dtype=out_dtype)
 
 
-def blast_matmul_grouped_w4a8_pallas(
-    xq: jax.Array,
-    sx: jax.Array,
-    U: jax.Array,
-    S: jax.Array,
-    V: jax.Array,
-    su: jax.Array,
-    ss: jax.Array,
-    sv: jax.Array,
-    *,
-    block_t: int = 128,
-    block_r: int = 128,
-    interpret: bool = False,
-    out_dtype=jnp.float32,
-) -> jax.Array:
+def blast_matmul_grouped_w4a8_pallas(xq, sx, U, S, V, su, ss, sv, *,
+                                     block_t: int = 128, block_r: int = 128,
+                                     interpret: bool = False,
+                                     out_dtype=jnp.float32):
     """Grouped W4A8: one launch, packed int4 factors (G,b,·,r/2), shared
     int8 activation codes → (G, T, m)."""
-    return _grouped_act_call(xq, sx, U, S, V, su, ss, sv, packed=True,
-                             block_t=block_t, block_r=block_r,
-                             interpret=interpret, out_dtype=out_dtype)
+    return _blast_call(xq, U, S, V, (su, ss, sv), sx, packed=True,
+                       block_t=block_t, block_r=block_r, interpret=interpret,
+                       out_dtype=out_dtype)

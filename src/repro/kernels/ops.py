@@ -1,7 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels: shape padding, block-size
-selection (VMEM budgeting + optional measured autotune cache), CPU interpret
-fallback, and the XLA einsum path used under GSPMD (pjit shards the einsum
-chain; the Pallas path is for shard_map-per-device execution on real TPUs)."""
+selection (VMEM budgeting + optional measured autotune cache), and the XLA
+einsum path used under GSPMD (pjit shards the einsum chain; the Pallas path
+is for shard_map-per-device execution on real TPUs).
+
+Where the kernels run is decided once, by ``_interpret``: compiled on the
+TPU, interpreted on the CPU (the test backend), and refused anywhere else."""
 
 from __future__ import annotations
 
@@ -13,7 +16,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro import quant as qt
 from repro.kernels import autotune, ref
-from repro.kernels.blast_matmul import (blast_matmul_grouped_pallas,
+from repro.kernels.blast_matmul import (VMEM_BUDGET,
+                                        blast_matmul_grouped_pallas,
                                         blast_matmul_grouped_q4_pallas,
                                         blast_matmul_grouped_q_pallas,
                                         blast_matmul_grouped_w4a8_pallas,
@@ -22,20 +26,40 @@ from repro.kernels.blast_matmul import (blast_matmul_grouped_pallas,
                                         blast_matmul_q4_pallas,
                                         blast_matmul_q_pallas,
                                         blast_matmul_w4a8_pallas,
-                                        blast_matmul_w8a8_pallas)
+                                        blast_matmul_w8a8_pallas, vmem_bytes)
 from repro.kernels.flash_attention import (flash_attention_pallas,
                                            flash_attention_prefill_pallas)
-
-# v5e VMEM is 16MB less a safety margin for double buffering.
-_VMEM_BUDGET = 8 * 1024 * 1024
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas kernels compile for the TPU and run interpreted on the CPU;
+    any other backend is an error, never a silent interpreter fallback."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels need a TPU (or the CPU "
+                           f"interpreter); the default backend is {backend!r}")
+    return backend == "cpu"
+
+
+def _rank_lane(packed: bool) -> int:
+    """Lane granule of ``block_r``: the r tile is the last (lane) axis of the
+    U/S/V blocks, so it must be a multiple of 128 — of packed *bytes* for
+    nibble-packed int4, i.e. 256 logical ranks."""
+    return 256 if packed else 128
+
+
+def _legal_block_r(block_r: int, r: int, packed: bool) -> int:
+    """A block_r the TPU compiler accepts: the whole padded rank when r fits
+    one lane granule (float/int8 pad r to 8, int4 to 256), else a multiple
+    of the granule."""
+    lane = _rank_lane(packed)
+    if r <= lane:
+        return lane if packed else _round_up(r, 8)
+    return max(lane, block_r // lane * lane)
 
 
 def pick_blast_blocks(T: int, m: int, n: int, b: int, r: int,
@@ -43,42 +67,44 @@ def pick_blast_blocks(T: int, m: int, n: int, b: int, r: int,
                       factor_bytes: float | None = None) -> tuple[int, int]:
     """Choose (block_t, block_r) so the VMEM resident set fits the budget.
 
-    Resident set ≈ x-tile (t·n) + z (b·t·r_t) + y-acc (t·m, fp32) +
-    U tile (p·r_t) + S (b²·r_t) + V (b·q·r_t).  ``factor_bytes`` sizes the
-    U/S/V terms when they differ from the activations (int8 factors with
-    float x, 0.5 for nibble-packed int4); it defaults to ``bytes_per_el``.
+    The resident set is ``blast_matmul.vmem_bytes``: double-buffered x / U /
+    S / V / out blocks plus the fp32 z and y scratch.  ``factor_bytes``
+    sizes the U/S/V terms when they differ from the activations (int8
+    factors with float x, 0.5 for nibble-packed int4); it defaults to
+    ``bytes_per_el``.  ``block_r`` comes from the legal lattice (multiples
+    of ``_rank_lane``, largest first); when nothing fits, the smallest legal
+    tiling is returned and the kernel raises its VMEM limit to match.
 
     Candidate ``block_t`` starts at the call's actual (rounded-up) T, not a
     flat 128: a decode-sized T=1..8 call must not budget VMEM for 128-row
     tiles it never materializes — that used to force needlessly small
     ``block_r`` for skinny calls.
     """
-    p, q = m // b, n // b
     fb = bytes_per_el if factor_bytes is None else factor_bytes
+    packed = fb < 1
+    lane = _rank_lane(packed)
+    r_cap = _round_up(r, lane)
     block_t = min(128, _round_up(max(T, 1), 8))
     while True:
-        for br in (128, 64, 32):
-            resident = (
-                block_t * n * bytes_per_el
-                + b * block_t * br * 4
-                + block_t * m * 4
-                + int((p * br + b * b * br + b * q * br) * fb)
-            )
-            if resident <= _VMEM_BUDGET:
+        for br in (4 * lane, 2 * lane, lane):
+            br = min(br, r_cap)
+            if vmem_bytes(block_t, br, m, n, b, bytes_per_el, fb,
+                          bytes_per_el) <= VMEM_BUDGET:
                 return block_t, br
-        if block_t <= 16:
-            break
-        block_t //= 2
-    return 8, 32
+        if block_t <= 8:
+            return 8, lane
+        block_t = max(8, block_t // 2)
 
 
 def _resolve_blocks(block_t: int | None, block_r: int | None, T: int, m: int,
                     n: int, b: int, r: int, x_dtype, factor_bytes,
                     G: int, kind: str, act: str = "none") -> tuple[int, int]:
     """Explicit blocks win; else the autotune cache (when enabled); else the
-    VMEM heuristic.  All inputs are trace-time statics.  ``act`` is the
-    activation storage ("none" | "int8") — part of the autotune key, since
-    int8 x-tiles shift the VMEM balance and the MXU path entirely."""
+    VMEM heuristic — either of the last two clamped to the call's T and
+    made legal for the TPU (``_legal_block_r``).  All inputs are trace-time
+    statics.  ``act`` is the activation storage ("none" | "int8") — part of
+    the autotune key, since int8 x-tiles shift the VMEM balance and the MXU
+    path entirely."""
     if block_t is not None and block_r is not None:
         return block_t, block_r
     x_bytes = 1 if act == "int8" else jnp.dtype(x_dtype).itemsize
@@ -90,7 +116,7 @@ def _resolve_blocks(block_t: int | None, block_r: int | None, T: int, m: int,
     else:
         bt, br = pick_blast_blocks(T, m, n, b, r, x_bytes, factor_bytes)
     block_t = block_t or min(bt, _round_up(T, 8))
-    block_r = block_r or min(br, _round_up(r, 8))
+    block_r = block_r or _legal_block_r(br, r, kind == "int4")
     return block_t, block_r
 
 
@@ -117,7 +143,7 @@ def _pad_last(a: jax.Array, target: int) -> jax.Array:
     return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, target - a.shape[-1])])
 
 
-@functools.partial(jax.jit, static_argnames=("block_t", "block_r", "interpret", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("block_t", "block_r", "use_pallas"))
 def blast_matmul(
     x: jax.Array,
     U: jax.Array,
@@ -126,13 +152,12 @@ def blast_matmul(
     *,
     block_t: int | None = None,
     block_r: int | None = None,
-    interpret: bool | None = None,
     use_pallas: bool = True,
 ) -> jax.Array:
     """x: (..., n) → (..., m).  Pads T and r to block multiples."""
     if not use_pallas:
         return ref.blast_matmul_ref(x, U, S, V)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     b, p, r = U.shape
     q = V.shape[1]
     m, n = b * p, b * q
@@ -147,7 +172,7 @@ def blast_matmul(
     return y[:T].reshape(*lead, m)
 
 
-@functools.partial(jax.jit, static_argnames=("block_t", "block_r", "interpret", "use_pallas"))
+@functools.partial(jax.jit, static_argnames=("block_t", "block_r", "use_pallas"))
 def blast_matmul_grouped(
     x: jax.Array,
     U: jax.Array,
@@ -156,7 +181,6 @@ def blast_matmul_grouped(
     *,
     block_t: int | None = None,
     block_r: int | None = None,
-    interpret: bool | None = None,
     use_pallas: bool = True,
 ) -> jax.Array:
     """Grouped BLAST matmul: G congruent factor sets over one shared input.
@@ -166,7 +190,7 @@ def blast_matmul_grouped(
     """
     if not use_pallas:
         return ref.blast_matmul_grouped_ref(x, U, S, V)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     G, b, p, r = U.shape
     q = V.shape[2]
     m, n = b * p, b * q
@@ -195,7 +219,7 @@ def _quantize_pad_x(xf: jax.Array, T: int,
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_r",
-                                             "interpret", "use_pallas", "act"))
+                                             "use_pallas", "act"))
 def blast_matmul_q(
     x: jax.Array,
     Uq: "qt.QArray",
@@ -204,7 +228,6 @@ def blast_matmul_q(
     *,
     block_t: int | None = None,
     block_r: int | None = None,
-    interpret: bool | None = None,
     use_pallas: bool = True,
     act: str = "none",
 ) -> jax.Array:
@@ -235,7 +258,7 @@ def blast_matmul_q(
             y = ref.blast_matmul_a8_ref(xq, sx, U8, S8, V8, su, ss, sv)
             return y.reshape(*lead, b * U8.shape[1]).astype(x.dtype)
         return ref.blast_matmul_q_ref(x, U8, S8, V8, su, ss, sv)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     if bits == {4}:
         b, p, r = Uq.shape            # logical (unpacked) factor shape
         q = Vq.shape[1]
@@ -279,7 +302,7 @@ def blast_matmul_q(
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_r",
-                                             "interpret", "use_pallas", "act"))
+                                             "use_pallas", "act"))
 def blast_matmul_grouped_q(
     x: jax.Array,
     U8: jax.Array,
@@ -291,7 +314,6 @@ def blast_matmul_grouped_q(
     *,
     block_t: int | None = None,
     block_r: int | None = None,
-    interpret: bool | None = None,
     use_pallas: bool = True,
     act: str = "none",
 ) -> jax.Array:
@@ -313,7 +335,7 @@ def blast_matmul_grouped_q(
                                                 su, ss, sv)
             return y.reshape(G, *lead, m).astype(x.dtype)
         return ref.blast_matmul_grouped_q_ref(x, U8, S8, V8, su, ss, sv)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     xf, lead, T = _flatten_x(x)
     block_t, block_r = _resolve_blocks(block_t, block_r, T, m, n, b, r,
                                        x.dtype, 1, G, "int8", act)
@@ -334,7 +356,7 @@ def blast_matmul_grouped_q(
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_r",
-                                             "interpret", "use_pallas", "act"))
+                                             "use_pallas", "act"))
 def blast_matmul_grouped_q4(
     x: jax.Array,
     Up: jax.Array,
@@ -346,7 +368,6 @@ def blast_matmul_grouped_q4(
     *,
     block_t: int | None = None,
     block_r: int | None = None,
-    interpret: bool | None = None,
     use_pallas: bool = True,
     act: str = "none",
 ) -> jax.Array:
@@ -372,7 +393,7 @@ def blast_matmul_grouped_q4(
                                                 su, ss, sv)
             return y.reshape(G, *lead, m).astype(x.dtype)
         return ref.blast_matmul_grouped_q_ref(x, U8, S8, V8, su, ss, sv)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     xf, lead, T = _flatten_x(x)
     block_t, block_r = _resolve_blocks(block_t, block_r, T, m, n, b, r,
                                        x.dtype, 0.5, G, "int4", act)
@@ -429,8 +450,7 @@ def _grouped_tp(inner, x, factors, scales, *, mesh, axis, kwargs):
 
 
 def blast_matmul_grouped_tp(x, U, S, V, *, mesh, axis="model",
-                            block_t=None, block_r=None, interpret=None,
-                            use_pallas=True):
+                            block_t=None, block_r=None, use_pallas=True):
     """``blast_matmul_grouped`` under shard_map: float factors rank-sharded
     over ``mesh.shape[axis]`` devices, one grouped launch per shard plus one
     stage-3 psum.  Falls back to the replicated single launch when the rank
@@ -438,13 +458,12 @@ def blast_matmul_grouped_tp(x, U, S, V, *, mesh, axis="model",
     return _grouped_tp(blast_matmul_grouped, x, (U, S, V), (),
                        mesh=mesh, axis=axis,
                        kwargs=dict(block_t=block_t, block_r=block_r,
-                                   interpret=interpret,
                                    use_pallas=use_pallas))
 
 
 def blast_matmul_grouped_q_tp(x, U8, S8, V8, su, ss, sv, *, mesh,
                               axis="model", block_t=None, block_r=None,
-                              interpret=None, use_pallas=True, act="none"):
+                              use_pallas=True, act="none"):
     """``blast_matmul_grouped_q`` under shard_map: int8 codes rank-sharded,
     per-block scales replicated (they are constant along r).  With
     ``act="int8"`` every shard quantizes the replicated x identically, so
@@ -452,13 +471,12 @@ def blast_matmul_grouped_q_tp(x, U8, S8, V8, su, ss, sv, *, mesh,
     return _grouped_tp(blast_matmul_grouped_q, x, (U8, S8, V8), (su, ss, sv),
                        mesh=mesh, axis=axis,
                        kwargs=dict(block_t=block_t, block_r=block_r,
-                                   interpret=interpret, use_pallas=use_pallas,
-                                   act=act))
+                                   use_pallas=use_pallas, act=act))
 
 
 def blast_matmul_grouped_q4_tp(x, Up, Sp, Vp, su, ss, sv, *, mesh,
                                axis="model", block_t=None, block_r=None,
-                               interpret=None, use_pallas=True, act="none"):
+                               use_pallas=True, act="none"):
     """``blast_matmul_grouped_q4`` under shard_map: the nibble-packed byte
     axis (r/2) is sharded, so factors stay packed per shard and unpack
     in-register as usual; tp must divide the byte count (else the replicated
@@ -466,12 +484,11 @@ def blast_matmul_grouped_q4_tp(x, Up, Sp, Vp, su, ss, sv, *, mesh,
     return _grouped_tp(blast_matmul_grouped_q4, x, (Up, Sp, Vp),
                        (su, ss, sv), mesh=mesh, axis=axis,
                        kwargs=dict(block_t=block_t, block_r=block_r,
-                                   interpret=interpret, use_pallas=use_pallas,
-                                   act=act))
+                                   use_pallas=use_pallas, act=act))
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "q_offset", "block_q", "block_kv", "interpret", "use_pallas"))
+    "causal", "window", "q_offset", "block_q", "block_kv", "use_pallas"))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -482,14 +499,13 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool | None = None,
     use_pallas: bool = True,
 ) -> jax.Array:
     """q: (B, Hq, T, D); k, v: (B, Hkv, S, D) → (B, Hq, T, D)."""
     if not use_pallas:
         return ref.attention_ref(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     B, Hq, T, D = q.shape
     S_len = k.shape[2]
     block_q = min(block_q, _round_up(T, 8))
@@ -508,7 +524,7 @@ def flash_attention(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_kv", "interpret", "use_pallas"))
+    "causal", "window", "block_q", "block_kv", "use_pallas"))
 def flash_attention_prefill(
     q: jax.Array,
     k: jax.Array,
@@ -519,7 +535,6 @@ def flash_attention_prefill(
     window: int | None = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool | None = None,
     use_pallas: bool = True,
 ) -> jax.Array:
     """Chunked-prefill attention at per-sequence offsets (continuous batching).
@@ -533,7 +548,7 @@ def flash_attention_prefill(
     if not use_pallas:
         return ref.attention_prefill_ref(q, k, v, q_offsets, causal=causal,
                                          window=window)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret()
     B, Hq, T, D = q.shape
     S_len = k.shape[2]
     block_q = min(block_q, _round_up(T, 8))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and dump the roofline inputs.
 
@@ -12,6 +9,17 @@ For each cell:  jax.jit(step, in_shardings, out_shardings).lower(SDS...)
 ``memory_analysis()`` (fits-per-device proof) and ``cost_analysis()``
 (FLOPs/bytes) and writes a JSON artifact with the parsed collective bytes —
 EXPERIMENTS.md §Dry-run/§Roofline read these files."""
+
+import os
+
+# The dry-run simulates production meshes on CPU devices: it pins the CPU
+# (it must never claim a chip another process is using) and adds the device
+# count to whatever XLA_FLAGS already holds.  Both must precede jax's
+# backend initialization.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512"
+                           ).strip()
 
 import argparse
 import json

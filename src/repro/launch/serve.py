@@ -26,6 +26,7 @@ import time
 import jax
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_parallel, make_serving_mesh, parse_mesh
 from repro.models import build_model
 from repro.parallel import NO_PARALLEL
@@ -139,7 +140,36 @@ def _print_resilience(engine: Engine):
               f"{len(fr['planned'])} planned — {fr['fired_by_kind']}")
 
 
-def main():
+def failures(engine: Engine, reqs) -> list[str]:
+    """Why a run without an armed fault plan must not count as finished:
+    requests that ended in ``error`` / ``numeric_error`` and recorded step
+    errors.  Empty when the run is clean, or when a fault plan was armed
+    (chaos runs fail requests on purpose)."""
+    if engine.fault_plan is not None:
+        return []
+    out = [f"request {r.uid} ended in {r.stop_reason}" for r in reqs
+           if r.stop_reason in ("error", "numeric_error")]
+    n_err = engine.stats["step_errors"]
+    if n_err:
+        out.append(f"{n_err} step errors: {engine.health.snapshot()}")
+    return out
+
+
+def make_prompts(vocab: int, n: int, lengths: str, seed: int) -> list:
+    """``n`` seeded prompts whose lengths spread evenly over
+    ``lengths`` = "LO,HI" tokens (inclusive)."""
+    lo, hi = (int(v) for v in lengths.split(","))
+    key = jax.random.PRNGKey(seed + 1)
+    prompts = []
+    for i in range(n):
+        plen = lo + (i * (hi - lo)) // max(1, n - 1)
+        prompt = jax.random.randint(jax.random.fold_in(key, i), (plen,),
+                                    0, vocab)
+        prompts.append([int(t) for t in prompt])
+    return prompts
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--structure", default=None)
@@ -147,6 +177,9 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
+    ap.add_argument("--prompt-len", default="4,8", metavar="LO,HI",
+                    help="batch-mode prompt lengths, spread evenly over "
+                         "LO..HI tokens")
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--chunk", type=int, default=16,
                     help="prompt tokens one slot may prefill per step")
@@ -218,8 +251,11 @@ def main():
     ap.add_argument("--report", default=None,
                     help="write a JSON throughput/SLA report here")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args):
+    """Config, model, seeded params and engine for parsed ``args``."""
     cfg = configs.get(args.arch, args.structure)
     if args.reduced:
         cfg = cfg.reduced()
@@ -236,6 +272,29 @@ def main():
     model = build_model(cfg, parallel)
     params = model.init(jax.random.PRNGKey(args.seed))
     engine = Engine(model, params, build_engine_config(args))
+    return cfg, model, params, engine
+
+
+def run_batch(engine: Engine, cfg, args) -> tuple[list, float]:
+    """Submit ``args.requests`` seeded prompts and drive to drain.
+    Returns the requests in submit order and the wall seconds."""
+    prompts = make_prompts(cfg.vocab, args.requests, args.prompt_len,
+                           args.seed)
+    # explicit small uids (1..N) so --fault-plan targets are addressable
+    reqs = [Request(uid=i + 1, prompt=p, max_new_tokens=args.max_new)
+            for i, p in enumerate(prompts)]
+    t0 = time.perf_counter()
+    for r in reqs:
+        engine.submit(r)
+    engine.run()
+    return reqs, time.perf_counter() - t0
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    cfg, model, params, engine = build(args)
+    parallel = getattr(model, "parallel", NO_PARALLEL)
     if parallel.active:
         rep = engine.sharding_report or {}
         print(f"[serve] mesh {parallel.dp_size}x{parallel.tp_size} "
@@ -295,24 +354,10 @@ def main():
             with open(args.report, "w") as f:
                 json.dump(report, f, indent=2, sort_keys=True)
             print(f"[serve] report written to {args.report}")
+        _exit_on_failures(engine, done)
         return
 
-    key = jax.random.PRNGKey(args.seed + 1)
-    prompts = []
-    for i in range(args.requests):
-        plen = 4 + (i % 5)
-        prompt = jax.random.randint(jax.random.fold_in(key, i), (plen,),
-                                    0, cfg.vocab)
-        prompts.append([int(t) for t in prompt])
-    # explicit small uids (1..N) so --fault-plan targets are addressable
-    reqs = [Request(uid=i + 1, prompt=p, max_new_tokens=args.max_new)
-            for i, p in enumerate(prompts)]
-    t0 = time.perf_counter()
-    for r in reqs:
-        engine.submit(r)
-    engine.run()
-    done = reqs
-    dt = time.perf_counter() - t0
+    done, dt = run_batch(engine, cfg, args)
     total_tokens = sum(len(r.output) for r in done)
     tp = engine.throughput()
     print(f"[serve] {len(done)} requests, {total_tokens} tokens "
@@ -338,6 +383,13 @@ def main():
         print(f"[serve] report written to {args.report}")
     for r in done[:4]:
         print(f"  req {r.uid}: prompt {len(r.prompt)} toks → {r.output[:8]}…")
+    _exit_on_failures(engine, done)
+
+
+def _exit_on_failures(engine: Engine, reqs):
+    bad = failures(engine, reqs)
+    if bad:
+        raise SystemExit("[serve] FAILED: " + "; ".join(bad))
 
 
 if __name__ == "__main__":

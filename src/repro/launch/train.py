@@ -18,6 +18,7 @@ import jax
 
 from repro import configs
 from repro.data import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, cosine_schedule
 from repro.parallel import NO_PARALLEL
@@ -25,6 +26,7 @@ from repro.train import Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--structure", default=None)

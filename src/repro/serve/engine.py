@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import heapq
 import threading
 import time
@@ -107,6 +108,14 @@ def _blast_shapes(tree) -> list[tuple[int, int, int, int]]:
         for v in tree.values():
             out += _blast_shapes(v)
     return out
+
+
+def _reset_row(batch_axis, cache, template, b):
+    """Overwrite row ``b`` of every cache leaf (along that leaf's batch
+    axis) with the one-row pristine ``template``."""
+    return jax.tree.map(
+        lambda bax, c, t: jax.lax.dynamic_update_slice_in_dim(c, t, b, bax),
+        batch_axis, cache, template)
 
 
 _LEGACY_WARNED = False
@@ -203,7 +212,8 @@ class Engine:
             self.cache = None
         else:
             self.cache = model.init_cache(self.B, mem.max_len)
-            self._template = self.cache  # pristine zero cache (reset source)
+            # one pristine row: the reset source for any slot
+            self._template = model.init_cache(1, mem.max_len)
         # per-leaf batch-axis position (stacked layer caches carry a leading
         # "layers" axis, so batch is NOT uniformly axis 0)
         axes = model.cache_axes()
@@ -211,6 +221,10 @@ class Engine:
             a is None or isinstance(a, str) for a in x))
         self._batch_axis = jax.tree.map(
             lambda ax: ax.index("batch"), axes, is_leaf=is_axes)
+        # in place: the reset consumes (donates) the cache it rewrites
+        self._reset_row = jax.jit(
+            functools.partial(_reset_row, self._batch_axis),
+            donate_argnums=(0,))
         self.slots = [_Slot() for _ in range(self.B)]
         self._rr = 0  # round-robin start for budget allocation
         self.queue: list = []   # heap of (prio_key, seq, Request)
@@ -276,19 +290,20 @@ class Engine:
             self.draft_params = jax.jit(
                 lambda p: model.truncate_params(p, plan))(self.params)
             if config.prestack and hasattr(model, "prestack_params"):
-                self.draft_params = jax.jit(model.prestack_params)(
-                    self.draft_params)
+                self.draft_params = model.prestack_params(self.draft_params)
             # the draft cache stays slot-static even in paged mode: it is
             # rewound/resynced every round, so it never holds a prefix worth
             # sharing, and k+1-token rounds keep its working set tiny
             self.draft_cache = model.init_cache(self.B, self.max_len)
-            self._draft_template = self.draft_cache
+            self._draft_template = model.init_cache(1, self.max_len)
             self._spec_round = jax.jit(self._make_spec_round())
             if self._pc is not None:
                 self._paged_spec = self._pc.make_spec_step(
                     self._make_spec_round())
         if config.prestack and hasattr(model, "prestack_params"):
-            self.params = jax.jit(model.prestack_params)(self.params)
+            # eager, not jitted: leaves the bundles do not replace stay the
+            # caller's arrays (a jit would return a copy of every weight)
+            self.params = model.prestack_params(self.params)
 
         # -- mesh parallelism: same engine code from 1 to N devices ---------
         self.parallel = getattr(model, "parallel", NO_PARALLEL)
@@ -336,7 +351,9 @@ class Engine:
         else:
             csh = shd.tree_shardings(self.cache, caxes, par)
             self.cache = jax.device_put(self.cache, csh)
-            self._template = self.cache
+            self._template = jax.device_put(
+                self._template,
+                shd.tree_shardings(self._template, caxes, par))
         if self.spec_k:
             self.draft_params = jax.device_put(
                 self.draft_params,
@@ -344,7 +361,9 @@ class Engine:
             self.draft_cache = jax.device_put(
                 self.draft_cache,
                 shd.tree_shardings(self.draft_cache, caxes, par))
-            self._draft_template = self.draft_cache
+            self._draft_template = jax.device_put(
+                self._draft_template,
+                shd.tree_shardings(self._draft_template, caxes, par))
         if par.tp_size > 1 and par.model_axis is not None:
             structures.set_tp_mesh(par.mesh, par.model_axis)
         self.sharding_report = shd.replication_report(self.params, axes, par)
@@ -775,18 +794,14 @@ class Engine:
     # -- internals --------------------------------------------------------------
 
     def _reset_slot(self, b: int):
-        def reset(bax, c, t):
-            idx = (slice(None),) * bax + (b,)
-            return c.at[idx].set(t[idx])
         if self._pc is not None:
             self._pc.reset_slot(b)
         else:
-            self.cache = jax.tree.map(reset, self._batch_axis, self.cache,
-                                      self._template)
+            self.cache = self._reset_row(self.cache, self._template,
+                                         jnp.int32(b))
         if self.spec_k:
-            self.draft_cache = jax.tree.map(
-                reset, self._batch_axis, self.draft_cache,
-                self._draft_template)
+            self.draft_cache = self._reset_row(
+                self.draft_cache, self._draft_template, jnp.int32(b))
 
     def _release_slot(self, b: int):
         """Free everything a departing request holds: its pages, its

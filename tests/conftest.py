@@ -1,5 +1,5 @@
-"""Imported before any test module: installs the JAX compat shims (via
-``import repro``) so test-module-level ``from jax.sharding import ...``
-bindings pick up the shimmed API on older jax."""
+"""Imported before any test module.  ``pyproject.toml`` puts ``src`` on the
+path; importing ``repro`` here stops the session with one clear error if
+the package does not resolve, instead of one per test module."""
 
 import repro  # noqa: F401

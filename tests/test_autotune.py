@@ -132,8 +132,7 @@ class TestTuning:
         want = ref.blast_matmul_ref(x, params.U, params.S, params.V)
         autotune.enable(str(tmp_path / "c.json"))
         autotune.tune_blast(4, 32, 32, 4, 8, reps=1)
-        got = ops.blast_matmul(x, params.U, params.S, params.V,
-                               interpret=True)
+        got = ops.blast_matmul(x, params.U, params.S, params.V)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-4, atol=3e-4)
 

@@ -51,9 +51,8 @@ class TestGroupedKernel:
         key = jax.random.PRNGKey(hash((G, T, b, p, q, r)) % 2**31)
         U, S, V = _rand_group(key, G, b, p, q, r, dtype)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, b * q), dtype=dtype)
-        got = ops.blast_matmul_grouped(x, U, S, V, interpret=True)
-        loop = jnp.stack([ops.blast_matmul(x, U[g], S[g], V[g],
-                                           interpret=True)
+        got = ops.blast_matmul_grouped(x, U, S, V)
+        loop = jnp.stack([ops.blast_matmul(x, U[g], S[g], V[g])
                           for g in range(G)])
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(loop, np.float32), **tol(dtype))
@@ -64,7 +63,7 @@ class TestGroupedKernel:
     def test_batched_leading_dims(self):
         U, S, V = _rand_group(jax.random.PRNGKey(0), 2, 4, 8, 8, 8)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 32))
-        got = ops.blast_matmul_grouped(x, U, S, V, interpret=True)
+        got = ops.blast_matmul_grouped(x, U, S, V)
         want = ref.blast_matmul_grouped_ref(x, U, S, V)
         assert got.shape == (2, 2, 5, 32)
         np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
@@ -76,15 +75,13 @@ class TestGroupedKernel:
         U, S, V = _rand_group(key, G, b, p, q, r)
         Uq, Sq, Vq, su, ss, sv = _quantize_group(U, S, V)
         x = jax.random.normal(jax.random.PRNGKey(2), (T, b * q))
-        got = ops.blast_matmul_grouped_q(x, Uq.q, Sq.q, Vq.q, su, ss, sv,
-                                         interpret=True)
+        got = ops.blast_matmul_grouped_q(x, Uq.q, Sq.q, Vq.q, su, ss, sv)
         loop = jnp.stack([
             ops.blast_matmul_q(
                 x,
                 qt.QArray(Uq.q[g], Uq.scale[g], 8),
                 qt.QArray(Sq.q[g], Sq.scale[g], 8),
-                qt.QArray(Vq.q[g], Vq.scale[g], 8),
-                interpret=True)
+                qt.QArray(Vq.q[g], Vq.scale[g], 8))
             for g in range(G)])
         np.testing.assert_allclose(np.asarray(got), np.asarray(loop),
                                    rtol=3e-4, atol=3e-4)
@@ -111,7 +108,7 @@ class TestInt4Kernel:
         S4 = qt.quantize(S, bits=4, block_axes=(2,))
         V4 = qt.quantize(V, bits=4, block_axes=(1, 2))
         x = jax.random.normal(jax.random.PRNGKey(3), (T, b * q))
-        got = ops.blast_matmul_q(x, U4, S4, V4, interpret=True)
+        got = ops.blast_matmul_q(x, U4, S4, V4)
         # the same int4 codes unpacked to int8 through the reference path
         want = ref.blast_matmul_q_ref(
             x, qt.int_values(U4), qt.int_values(S4), qt.int_values(V4),
@@ -120,8 +117,7 @@ class TestInt4Kernel:
                                    rtol=2e-5, atol=2e-5)
         # and through the int8 *kernel* on identical codes
         as8 = lambda a: qt.QArray(qt.int_values(a), a.scale, 8)
-        got8 = ops.blast_matmul_q(x, as8(U4), as8(S4), as8(V4),
-                                  interpret=True)
+        got8 = ops.blast_matmul_q(x, as8(U4), as8(S4), as8(V4))
         np.testing.assert_allclose(np.asarray(got), np.asarray(got8),
                                    rtol=2e-5, atol=2e-5)
 
@@ -147,7 +143,7 @@ class TestInt4Kernel:
 
         monkeypatch.setattr(ops, "blast_matmul_q4_pallas", spy)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, b * q))
-        y = ops.blast_matmul_q(x, U4, S4, V4, interpret=True)
+        y = ops.blast_matmul_q(x, U4, S4, V4)
         assert y.shape == (T, b * p)
         r_pad = ((r + seen["block_r"] - 1) // seen["block_r"]) * seen["block_r"]
         assert seen["shapes"] == ((b, p, r_pad // 2), (b, b, r_pad // 2),
@@ -181,15 +177,13 @@ class TestGroupedQ4Kernel:
         Uq, Sq, Vq, su, ss, sv = _quantize_group(U, S, V, bits=4)
         assert Uq.q.dtype == jnp.uint8          # packed bytes in, packed out
         x = jax.random.normal(jax.random.PRNGKey(2), (T, b * q))
-        got = ops.blast_matmul_grouped_q4(x, Uq.q, Sq.q, Vq.q, su, ss, sv,
-                                          interpret=True)
+        got = ops.blast_matmul_grouped_q4(x, Uq.q, Sq.q, Vq.q, su, ss, sv)
         loop = jnp.stack([
             ops.blast_matmul_q(
                 x,
                 qt.QArray(Uq.q[g], Uq.scale[g], 4, last_dim=r),
                 qt.QArray(Sq.q[g], Sq.scale[g], 4, last_dim=r),
-                qt.QArray(Vq.q[g], Vq.scale[g], 4, last_dim=r),
-                interpret=True)
+                qt.QArray(Vq.q[g], Vq.scale[g], 4, last_dim=r))
             for g in range(G)])
         np.testing.assert_allclose(np.asarray(got), np.asarray(loop),
                                    rtol=3e-4, atol=3e-4)
@@ -206,10 +200,10 @@ class TestGroupedQ4Kernel:
         x = jax.random.normal(jax.random.PRNGKey(2), (T, b * q))
         if bits == 4:
             got = ops.blast_matmul_grouped_q4(x, Uq.q, Sq.q, Vq.q, su, ss, sv,
-                                              act="int8", interpret=True)
+                                              act="int8")
         else:
             got = ops.blast_matmul_grouped_q(x, Uq.q, Sq.q, Vq.q, su, ss, sv,
-                                             act="int8", interpret=True)
+                                             act="int8")
         xq, sx = qt.quantize_act(x)
         want = ref.blast_matmul_grouped_a8_ref(
             xq, sx, qt.int_values(Uq), qt.int_values(Sq), qt.int_values(Vq),
@@ -416,3 +410,27 @@ class TestPickBlocksTClamp:
         _, br_decode = ops.pick_blast_blocks(1, 8192, 8192, 16, 2048)
         _, br_prefill = ops.pick_blast_blocks(512, 8192, 8192, 16, 2048)
         assert br_decode >= br_prefill
+
+
+class TestTpuLegalBlocks:
+    """Every heuristic tiling is one the TPU compiler accepts: block_r a
+    multiple of 128 (packed int4: 128 bytes = 256 ranks) or the whole rank
+    padded to 8, and the resident set within the VMEM budget."""
+
+    @pytest.mark.parametrize("T", [1, 8, 128])
+    @pytest.mark.parametrize("m,n,r", [(4096, 4096, 1024),
+                                       (11008, 4096, 1488),
+                                       (4096, 11008, 1488),
+                                       (576, 576, 112)])
+    @pytest.mark.parametrize("kind,fb", [("float", 2), ("int8", 1),
+                                         ("int4", 0.5)])
+    def test_heuristic_blocks_are_legal(self, T, m, n, r, kind, fb):
+        from repro.kernels.blast_matmul import VMEM_BUDGET, vmem_bytes
+        bt, br = ops._resolve_blocks(None, None, T, m, n, 16, r,
+                                     jnp.bfloat16, fb, 1, kind)
+        lane = 256 if kind == "int4" else 128
+        whole = r <= lane and br == (lane if kind == "int4"
+                                     else ops._round_up(r, 8))
+        assert br % lane == 0 or whole, br
+        assert bt % 8 == 0 and bt <= max(8, ops._round_up(T, 8))
+        assert vmem_bytes(bt, br, m, n, 16, 2, fb, 2) <= VMEM_BUDGET

@@ -32,7 +32,7 @@ class TestBlastKernel:
         key = jax.random.PRNGKey(hash((T, m, n, b, r)) % 2**31)
         params = blast.init(key, m, n, b, r, dtype=dtype)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, n), dtype=dtype)
-        got = blast_matmul(x, params.U, params.S, params.V, interpret=True)
+        got = blast_matmul(x, params.U, params.S, params.V)
         want = ref.blast_matmul_ref(x, params.U, params.S, params.V)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), **tol(dtype))
@@ -40,7 +40,7 @@ class TestBlastKernel:
     def test_batched_leading_dims(self):
         params = blast.init(jax.random.PRNGKey(0), 32, 32, 4, 8)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 32))
-        got = blast_matmul(x, params.U, params.S, params.V, interpret=True)
+        got = blast_matmul(x, params.U, params.S, params.V)
         want = ref.blast_matmul_ref(x, params.U, params.S, params.V)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -49,7 +49,7 @@ class TestBlastKernel:
         x = jax.random.normal(jax.random.PRNGKey(1), (64, 64))
         for bt, br in [(16, 8), (32, 16), (64, 32)]:
             got = blast_matmul(x, params.U, params.S, params.V,
-                               block_t=bt, block_r=br, interpret=True)
+                               block_t=bt, block_r=br)
             want = ref.blast_matmul_ref(x, params.U, params.S, params.V)
             np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
@@ -97,7 +97,7 @@ class TestBlastKernelIntActivations:
         params = blast.init(key, m, n, b, r)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, n))
         Uq, Sq, Vq = _quantize_blast_factors(params, bits)
-        got = blast_matmul_q(x, Uq, Sq, Vq, act="int8", interpret=True)
+        got = blast_matmul_q(x, Uq, Sq, Vq, act="int8")
         xq, sx = qt.quantize_act(x)
         want = ref.blast_matmul_a8_ref(
             xq, sx, qt.int_values(Uq), qt.int_values(Sq), qt.int_values(Vq),
@@ -112,9 +112,8 @@ class TestBlastKernelIntActivations:
         params = blast.init(jax.random.PRNGKey(0), m, n, b, r)
         x = jax.random.normal(jax.random.PRNGKey(2), (T, n))
         Uq, Sq, Vq = _quantize_blast_factors(params, bits)
-        a8 = np.asarray(blast_matmul_q(x, Uq, Sq, Vq, act="int8",
-                                       interpret=True), np.float64)
-        w_only = np.asarray(blast_matmul_q(x, Uq, Sq, Vq, interpret=True),
+        a8 = np.asarray(blast_matmul_q(x, Uq, Sq, Vq, act="int8"), np.float64)
+        w_only = np.asarray(blast_matmul_q(x, Uq, Sq, Vq),
                             np.float64)
         _, sx = qt.quantize_act(x)
         bound = _act_bound(np.asarray(sx), Uq, Sq, Vq)
@@ -125,7 +124,7 @@ class TestBlastKernelIntActivations:
         Uq, Sq, Vq = _quantize_blast_factors(params, 8)
         for dtype in (jnp.float32, jnp.bfloat16):
             x = jax.random.normal(jax.random.PRNGKey(4), (4, 32), dtype=dtype)
-            y = blast_matmul_q(x, Uq, Sq, Vq, act="int8", interpret=True)
+            y = blast_matmul_q(x, Uq, Sq, Vq, act="int8")
             assert y.dtype == dtype and y.shape == (4, 32)
 
 
@@ -149,8 +148,7 @@ class TestFlashAttention:
         v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype=dtype)
         q_offset = S - T  # decode semantics when S > T
         got = flash_attention(q, k, v, causal=causal, window=window,
-                              q_offset=q_offset, block_q=32, block_kv=32,
-                              interpret=True)
+                              q_offset=q_offset, block_q=32, block_kv=32)
         want = ref.attention_ref(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset)
         np.testing.assert_allclose(
@@ -164,7 +162,7 @@ class TestFlashAttention:
         k = jax.random.normal(ks[1], (B, H, T, D))
         v = jax.random.normal(ks[2], (B, H, T, D))
         got = flash_attention(q, k, v, causal=True, window=W,
-                              block_q=64, block_kv=64, interpret=True)
+                              block_q=64, block_kv=64)
         want = ref.attention_ref(q, k, v, causal=True, window=W)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -191,7 +189,7 @@ class TestFlashAttentionPrefill:
         # per-row offsets: fresh slot, mid-stream slot, nearly-full slot
         offs = jnp.asarray([0, (S - C) // 2, S - C][:B], jnp.int32)
         got = flash_attention_prefill(q, k, v, offs, window=window,
-                                      block_q=8, block_kv=32, interpret=True)
+                                      block_q=8, block_kv=32)
         want = ref.attention_prefill_ref(q, k, v, offs, window=window)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -208,9 +206,8 @@ class TestFlashAttentionPrefill:
         off = S - C
         got = flash_attention_prefill(
             q, k, v, jnp.full((B,), off, jnp.int32),
-            block_q=8, block_kv=32, interpret=True)
-        want = flash_attention(q, k, v, q_offset=off, block_q=8, block_kv=32,
-                               interpret=True)
+            block_q=8, block_kv=32)
+        want = flash_attention(q, k, v, q_offset=off, block_q=8, block_kv=32)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
 
@@ -223,7 +220,7 @@ class TestDecodeShapes:
     def test_blast_matvec_t1(self):
         params = blast.init(jax.random.PRNGKey(0), 128, 128, 16, 24)
         x = jax.random.normal(jax.random.PRNGKey(1), (1, 128))
-        got = blast_matmul(x, params.U, params.S, params.V, interpret=True)
+        got = blast_matmul(x, params.U, params.S, params.V)
         want = ref.blast_matmul_ref(x, params.U, params.S, params.V)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
@@ -233,6 +230,6 @@ class TestDecodeShapes:
         k = jax.random.normal(ks[1], (2, 2, 128, 16))
         v = jax.random.normal(ks[2], (2, 2, 128, 16))
         got = flash_attention(q, k, v, causal=True, q_offset=127,
-                              block_q=8, block_kv=32, interpret=True)
+                              block_q=8, block_kv=32)
         want = ref.attention_ref(q, k, v, causal=True, q_offset=127)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)

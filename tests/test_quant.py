@@ -284,7 +284,7 @@ class TestBlastKernelInt8:
         params = blast.init(jax.random.PRNGKey(T + m), m, n, b, r)
         x = jax.random.normal(jax.random.PRNGKey(1), (T, n))
         Uq, Sq, Vq = _quantize_blast(params)
-        got = blast_matmul_q(x, Uq, Sq, Vq, interpret=True)
+        got = blast_matmul_q(x, Uq, Sq, Vq)
         want = ref.blast_matmul_q_ref(
             x, qt.int_values(Uq), qt.int_values(Sq), qt.int_values(Vq),
             Uq.scale.reshape(b), Sq.scale.reshape(b, b), Vq.scale.reshape(b))
@@ -297,7 +297,7 @@ class TestBlastKernelInt8:
         params = blast.init(jax.random.PRNGKey(0), m, n, b, r)
         x = jax.random.normal(jax.random.PRNGKey(2), (T, n))
         Uq, Sq, Vq = _quantize_blast(params)
-        got = np.asarray(blast_matmul_q(x, Uq, Sq, Vq, interpret=True),
+        got = np.asarray(blast_matmul_q(x, Uq, Sq, Vq),
                          np.float64)
         want = np.asarray(ref.blast_matmul_ref(x, params.U, params.S,
                                                params.V), np.float64)
@@ -308,7 +308,7 @@ class TestBlastKernelInt8:
         params = blast.init(jax.random.PRNGKey(3), 32, 32, 4, 8)
         x = jax.random.normal(jax.random.PRNGKey(4), (16, 32))
         Uq, Sq, Vq = _quantize_blast(params, bits=4)
-        got = blast_matmul_q(x, Uq, Sq, Vq, interpret=True)
+        got = blast_matmul_q(x, Uq, Sq, Vq)
         want = ref.blast_matmul_q_ref(
             x, qt.int_values(Uq), qt.int_values(Sq), qt.int_values(Vq),
             Uq.scale.reshape(4), Sq.scale.reshape(4, 4), Vq.scale.reshape(4))

@@ -446,3 +446,57 @@ class TestHTTPResilience:
         assert [e["token"] for e in events[:-1]] == ref
         assert events[-1]["done"] and events[-1]["stop_reason"] == "length"
         eng.close()
+
+
+class TestLauncherExitCode:
+    """``launch/serve.py`` must not report a failed run as finished: with
+    no fault plan armed, an errored request or a recorded step error is a
+    non-zero exit; an armed plan fails requests on purpose."""
+
+    _ARGV = ["--arch", "smollm-135m", "--reduced", "--requests", "2",
+             "--max-new", "2", "--chunk", "8", "--max-len", "32"]
+
+    def test_clean_run_has_no_failures(self):
+        from repro.launch import serve
+        model, params = _tiny()
+        reqs = _reqs(2, 4)
+        eng = _serve(model, params, _config(), reqs)
+        assert serve.failures(eng, reqs) == []
+
+    def test_unplanned_step_error_is_a_failure(self):
+        from repro.launch import serve
+        model, params = _tiny()
+        eng = Engine(model, params, _config())
+
+        def refused(*a):
+            raise RuntimeError("the device refused the step")
+        eng._step = refused
+        reqs = _reqs(2, 4)
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        eng.close()
+        assert {r.stop_reason for r in reqs} == {"error"}
+        bad = serve.failures(eng, reqs)
+        assert any("ended in error" in b for b in bad)
+        assert any("step errors" in b for b in bad)
+
+    def test_armed_fault_plan_is_not_a_failure(self):
+        from repro.launch import serve
+        model, params = _tiny()
+        cfg = _config(res=ResilienceConfig(fault_spec="raise@2:u1"))
+        reqs = _reqs(3, 4)
+        eng = _serve(model, params, cfg, reqs)
+        assert eng.stats["step_errors"] >= 1
+        assert serve.failures(eng, reqs) == []
+
+    def test_main_exits_nonzero_on_step_errors(self, monkeypatch):
+        from repro.launch import serve
+        from repro.serve import engine as engine_mod
+
+        def refused(self, finished):
+            raise RuntimeError("the device refused the step")
+        monkeypatch.setattr(engine_mod.Engine, "_advance", refused)
+        with pytest.raises(SystemExit) as exc:
+            serve.main(self._ARGV)
+        assert "FAILED" in str(exc.value.code)

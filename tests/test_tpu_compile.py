@@ -1,0 +1,122 @@
+"""Compile the main-path kernels and the serving step for a described TPU
+v5e, without the chip.
+
+Interpret mode (every other kernel test) accepts tilings the TPU compiler
+refuses: unaligned lane slices, block shapes off the (8, 128) tiling, more
+VMEM than a kernel may use.  These tests run the chip's own compiler
+(installed with libtpu) against a ``v5e:2x2`` topology description and
+assert the compiled program holds a Mosaic kernel (``tpu_custom_call``),
+so none of them ran interpreted.  The topology is described inside a
+fixture, never at import: only the worker that runs this file loads the
+TPU library.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro import configs, quant as qt
+from repro.kernels import ops
+from repro.models import build_model
+
+B16 = 16
+# (name, d_out m, d_in n, rank r): llama7b-blast attention / FFN up / FFN
+# down (paper Table 9) and a smollm-135m projection (p = q = 36 lanes).
+SHAPES = [("attn", 4096, 4096, 1024), ("up", 11008, 4096, 1488),
+          ("down", 4096, 11008, 1488), ("smollm", 576, 576, 112)]
+KINDS = ["float", "grouped", "int8", "int4"]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """``ops`` interprets the kernels on the CPU backend; steer it to the
+    compiled kernel, which is what a TPU backend selects."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _blast_call(kind, m, n, r, T, sh):
+    """(fresh jit of the ops wrapper, abstract operands) for one kernel."""
+    b = B16
+    p, q = m // b, n // b
+    x = _sds((T, n), jnp.bfloat16, sh)
+    if kind in ("float", "grouped"):
+        lead = (2,) if kind == "grouped" else ()
+        fac = [_sds(lead + s, jnp.bfloat16, sh)
+               for s in ((b, p, r), (b, b, r), (b, q, r))]
+        fn = ops.blast_matmul_grouped if lead else ops.blast_matmul
+        return jax.jit(fn.__wrapped__), (x, *fac)
+    bits = 8 if kind == "int8" else 4
+    rs = r if bits == 8 else (r + 1) // 2
+    code = jnp.int8 if bits == 8 else jnp.uint8
+
+    def qa(shape, scale_shape):
+        return qt.QArray(_sds(shape[:-1] + (rs,), code, sh),
+                         _sds(scale_shape, jnp.float32, sh), bits=bits,
+                         last_dim=shape[-1])
+    fac = [qa((b, p, r), (b, 1, 1)), qa((b, b, r), (b, b, 1)),
+           qa((b, q, r), (b, 1, 1))]
+    return jax.jit(ops.blast_matmul_q.__wrapped__), (x, *fac)
+
+
+@pytest.mark.parametrize("T", [8, 128])
+@pytest.mark.parametrize("shape", SHAPES, ids=[s[0] for s in SHAPES])
+@pytest.mark.parametrize("kind", KINDS)
+def test_blast_kernel_compiles(one_chip, compiled_kernels, kind, shape, T):
+    _, m, n, r = shape
+    fn, args = _blast_call(kind, m, n, r, T, one_chip)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip, compiled_kernels):
+    q = _sds((1, 32, 1024, 128), jnp.bfloat16, one_chip)
+    fn = jax.jit(ops.flash_attention.__wrapped__)
+    compiled = fn.lower(q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_llama7b_prefill_chunk_fits_one_chip(one_chip):
+    """One serving step of llama7b-blast at full width (4 slots × chunk 16,
+    max_len 512, bf16) on the engine's pre-stacked params: arguments +
+    outputs + temporaries under 16 GB."""
+    model = build_model(configs.get("llama7b-blast"))
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = place(jax.eval_shape(
+        lambda k: model.prestack_params(model.init(k)),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(4, 512)))
+    tokens = _sds((4, 16), jnp.int32, one_chip)
+    steps = _sds((4,), jnp.int32, one_chip)
+    compiled = jax.jit(model.prefill_chunk, donate_argnums=(1,)).lower(
+        params, cache, tokens, steps, steps).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 16e9, mem
