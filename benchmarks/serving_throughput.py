@@ -38,12 +38,13 @@ from repro.core import structures
 from repro.models import build_model
 from repro.quant import QuantConfig
 from repro.serve import (Engine, EngineConfig, MemoryConfig, Request,
-                        SamplingParams, SchedulerConfig, SpeculativeConfig)
+                        SamplingParams, SchedulerConfig, SpeculativeConfig,
+                        Tracer)
 
 
 def _percentiles(samples) -> dict:
     """Per-step latency percentiles (p50/p90/p99) in seconds."""
-    if not samples:
+    if len(samples) == 0:
         return {"p50": 0.0, "p90": 0.0, "p99": 0.0}
     arr = np.asarray(samples, np.float64)
     return {f"p{p}": float(np.percentile(arr, p)) for p in (50, 90, 99)}
@@ -98,6 +99,10 @@ def run(quiet=False, n_requests=8, slots=4, chunks=(1, 8, 32)):
         assert len(done) == n_requests
         tp = eng.throughput()
         hbm_mb = (qt.tree_nbytes(eng.params) + qt.tree_nbytes(eng.cache)) / 2**20
+        # a step's wall time: its dispatch and the wait for its logits
+        ticks = eng.tracer.records()
+        step_s = ticks["dispatch"] + ticks["wait"]
+        decode_only = (ticks["prompt"] == 0) & (ticks["decode"] > 0)
         rows.append({
             "chunk": chunk,
             "steps": tp["steps"],
@@ -105,8 +110,8 @@ def run(quiet=False, n_requests=8, slots=4, chunks=(1, 8, 32)):
             "decode_tok_s": tp["decode_tok_s"],
             "wall_s": wall,
             "weight_cache_mb": hbm_mb,
-            "step_latency_s": _percentiles(eng.stats["step_s"]),
-            "decode_step_latency_s": _percentiles(eng.stats["decode_step_s"]),
+            "step_latency_s": _percentiles(step_s),
+            "decode_step_latency_s": _percentiles(step_s[decode_only]),
         })
         if not quiet:
             pct = rows[-1]["decode_step_latency_s"]
@@ -280,8 +285,7 @@ def speculative_report(quiet=False, k=7, frac=None, decay=0.5,
                 eng.submit(r)
             eng.run()           # warm (compile) …
             for key_ in eng.stats:  # … drop compile time from the record
-                eng.stats[key_] = ([] if isinstance(eng.stats[key_], list)
-                                   else 0)
+                eng.stats[key_] = 0
             for r in mk_reqs():
                 eng.submit(r)
             done = eng.run()    # … then the timed workload on a hot engine
@@ -363,7 +367,8 @@ def _run_trace(model, params, trace, *, policy, pages, slots=4, max_len=128,
     for timed in (False, True):   # warm pass compiles every step variant …
         if timed:                 # … so the timed pass measures scheduling
             for k, v in eng.stats.items():
-                eng.stats[k] = [] if isinstance(v, list) else type(v)(0)
+                eng.stats[k] = type(v)(0)
+            eng.tracer = Tracer()
             eng.finished.clear()
         pending = sorted(trace, key=lambda e: e[0])
         i, tick = 0, 0

@@ -666,15 +666,17 @@ def row_health(logits, absmax: float | None = None):
     draft ranks) are exactly where overflow/NaN faults originate, and one
     cheap reduction here is what lets a poisoned row degrade gracefully
     instead of wedging the batch.  Reduces over every non-batch axis, so it
-    accepts (B, V), (B, C, V) and any wider logits layout."""
+    accepts (B, V), (B, C, V) and any wider logits layout.  Traced under
+    the name scope ``guardrail``."""
     axes = tuple(range(1, logits.ndim))
-    finite = jnp.isfinite(logits)
-    ok = finite.all(axis=axes)
-    if absmax is not None:
-        # mask non-finite entries out of the max so inf does not shadow the
-        # finiteness bit with a second (redundant) trip reason
-        mag = jnp.abs(jnp.where(finite, logits, 0.0)).max(axis=axes)
-        ok = ok & (mag <= absmax)
+    with jax.named_scope("guardrail"):
+        finite = jnp.isfinite(logits)
+        ok = finite.all(axis=axes)
+        if absmax is not None:
+            # mask non-finite entries out of the max so inf does not shadow
+            # the finiteness bit with a second (redundant) trip reason
+            mag = jnp.abs(jnp.where(finite, logits, 0.0)).max(axis=axes)
+            ok = ok & (mag <= absmax)
     return ok
 
 

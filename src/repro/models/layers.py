@@ -47,14 +47,16 @@ def linear_init(spec: LinearSpec, key, dtype, *, scale=None, bias: bool = False)
 
 def linear_apply(spec: LinearSpec, params: Params, x: jax.Array) -> jax.Array:
     """Storage-format-aware apply: QArray params route to the structure's
-    fused-dequant ``apply_q`` path, float params to the plain ``apply``."""
+    fused-dequant ``apply_q`` path, float params to the plain ``apply``.
+    Traced under the name scope ``linear``."""
     structures.record_dispatch(1)
-    if any(qt.is_qarray(v) for v in params.values()):
-        y = spec.apply_q(params, x)
-    else:
-        y = spec.apply(params, x)
-    if "bias" in params:
-        y = y + params["bias"]
+    with jax.named_scope("linear"):
+        if any(qt.is_qarray(v) for v in params.values()):
+            y = spec.apply_q(params, x)
+        else:
+            y = spec.apply(params, x)
+        if "bias" in params:
+            y = y + params["bias"]
     return y
 
 
@@ -81,9 +83,11 @@ def linear_group_apply(specs: Sequence[LinearSpec],
     stacked = None
     if isinstance(bundle, structures.GroupBundle) and bundle.plan == plan:
         stacked = bundle.arrays
-    ys = structures.group_apply(specs, core, x, plan=plan, stacked=stacked)
-    return [y + p["bias"] if "bias" in p else y
-            for y, p in zip(ys, params_list)]
+    with jax.named_scope("linear"):
+        ys = structures.group_apply(specs, core, x, plan=plan,
+                                    stacked=stacked)
+        return [y + p["bias"] if "bias" in p else y
+                for y, p in zip(ys, params_list)]
 
 
 def linear_group_prestack(specs: Sequence[LinearSpec],
@@ -411,44 +415,54 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
     survives = valid & (offs[None, :] + S >= n_tokens[:, None])
     slot = jnp.where(survives, q_pos % S, S)         # S = OOB → dropped
     new_cache = dict(cache)
-    k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
-    new_cache["pos"] = k_pos
-    if spec.cfg.cache_quant:
-        kq, ks = qt.quantize_rows(k)
-        vq, vs = qt.quantize_rows(v)
-        new_cache["k"] = cache["k"].at[rows, slot].set(kq, mode="drop")
-        new_cache["v"] = cache["v"].at[rows, slot].set(vq, mode="drop")
-        new_cache["k_scale"] = cache["k_scale"].at[rows, slot].set(ks, mode="drop")
-        new_cache["v_scale"] = cache["v_scale"].at[rows, slot].set(vs, mode="drop")
-        k_cache = qt.dequantize_rows(new_cache["k"], new_cache["k_scale"], x.dtype)
-        v_cache = qt.dequantize_rows(new_cache["v"], new_cache["v_scale"], x.dtype)
-    else:
-        k_cache = cache["k"].at[rows, slot].set(k, mode="drop")
-        v_cache = cache["v"].at[rows, slot].set(v, mode="drop")
-        new_cache["k"], new_cache["v"] = k_cache, v_cache
-    if spec.window is not None and C > 1:
-        # Ring hazard: within one chunk a later token may overwrite a slot
-        # still inside an earlier query's window.  Attend over the pre-write
-        # ring ‖ the chunk itself — the position mask picks the right keys.
-        kv_pos = jnp.where(valid, q_pos, -1)
+    with jax.named_scope("cache_update"):
+        k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
+        new_cache["pos"] = k_pos
         if spec.cfg.cache_quant:
-            k_old = qt.dequantize_rows(cache["k"], cache["k_scale"], x.dtype)
-            v_old = qt.dequantize_rows(cache["v"], cache["v_scale"], x.dtype)
-            # attend to the chunk's own keys through the same int8
-            # round-trip the C=1 path reads back from the cache
-            k = qt.dequantize_rows(kq, ks, x.dtype)
-            v = qt.dequantize_rows(vq, vs, x.dtype)
+            kq, ks = qt.quantize_rows(k)
+            vq, vs = qt.quantize_rows(v)
+            new_cache["k"] = cache["k"].at[rows, slot].set(kq, mode="drop")
+            new_cache["v"] = cache["v"].at[rows, slot].set(vq, mode="drop")
+            new_cache["k_scale"] = cache["k_scale"].at[rows, slot].set(
+                ks, mode="drop")
+            new_cache["v_scale"] = cache["v_scale"].at[rows, slot].set(
+                vs, mode="drop")
+            k_cache = qt.dequantize_rows(new_cache["k"], new_cache["k_scale"],
+                                         x.dtype)
+            v_cache = qt.dequantize_rows(new_cache["v"], new_cache["v_scale"],
+                                         x.dtype)
         else:
-            k_old, v_old = cache["k"], cache["v"]
-        o = ops.cache_attention(
-            q.transpose(0, 2, 1, 3),
-            jnp.concatenate([k_old, k.astype(k_old.dtype)], axis=1),
-            jnp.concatenate([v_old, v.astype(v_old.dtype)], axis=1),
-            jnp.concatenate([cache["pos"], kv_pos], axis=1),
-            q_pos, window=spec.window)
-    else:
-        o = ops.cache_attention(q.transpose(0, 2, 1, 3), k_cache, v_cache,
-                                k_pos, q_pos, window=spec.window)
+            k_cache = cache["k"].at[rows, slot].set(k, mode="drop")
+            v_cache = cache["v"].at[rows, slot].set(v, mode="drop")
+            new_cache["k"], new_cache["v"] = k_cache, v_cache
+    with jax.named_scope("attention"):
+        if spec.window is not None and C > 1:
+            # Ring hazard: within one chunk a later token may overwrite a
+            # slot still inside an earlier query's window.  Attend over the
+            # pre-write ring ‖ the chunk itself — the position mask picks
+            # the right keys.
+            kv_pos = jnp.where(valid, q_pos, -1)
+            if spec.cfg.cache_quant:
+                k_old = qt.dequantize_rows(cache["k"], cache["k_scale"],
+                                           x.dtype)
+                v_old = qt.dequantize_rows(cache["v"], cache["v_scale"],
+                                           x.dtype)
+                # attend to the chunk's own keys through the same int8
+                # round-trip the C=1 path reads back from the cache
+                k = qt.dequantize_rows(kq, ks, x.dtype)
+                v = qt.dequantize_rows(vq, vs, x.dtype)
+            else:
+                k_old, v_old = cache["k"], cache["v"]
+            o = ops.cache_attention(
+                q.transpose(0, 2, 1, 3),
+                jnp.concatenate([k_old, k.astype(k_old.dtype)], axis=1),
+                jnp.concatenate([v_old, v.astype(v_old.dtype)], axis=1),
+                jnp.concatenate([cache["pos"], kv_pos], axis=1),
+                q_pos, window=spec.window)
+        else:
+            o = ops.cache_attention(q.transpose(0, 2, 1, 3), k_cache,
+                                    v_cache, k_pos, q_pos,
+                                    window=spec.window)
     # o is (B, Hq, C, hd) — token-major flatten needs the transpose (a
     # straight reshape is only layout-neutral at C=1)
     y = linear_apply(spec.out, params["out"],
@@ -663,44 +677,55 @@ def mla_prefill(spec: MLASpec, params: Params, cache: Params, x: jax.Array,
     S = cache["latent"].shape[1]
     slot = jnp.where(valid, q_pos, S)                # MLA cache is not a ring
     new_cache: Params = {}
-    if spec.cfg.cache_quant:
-        lq, ls = qt.quantize_rows(latent)
-        rq, rs = qt.quantize_rows(k_rope)
-        new_cache["latent"] = cache["latent"].at[rows, slot].set(lq, mode="drop")
-        new_cache["k_rope"] = cache["k_rope"].at[rows, slot].set(rq, mode="drop")
-        new_cache["latent_scale"] = cache["latent_scale"].at[rows, slot].set(
-            ls, mode="drop")
-        new_cache["k_rope_scale"] = cache["k_rope_scale"].at[rows, slot].set(
-            rs, mode="drop")
-        lat_cache = qt.dequantize_rows(new_cache["latent"],
-                                       new_cache["latent_scale"], x.dtype)
-        rope_cache = qt.dequantize_rows(new_cache["k_rope"],
-                                        new_cache["k_rope_scale"], x.dtype)
-    else:
-        lat_cache = cache["latent"].at[rows, slot].set(latent, mode="drop")
-        rope_cache = cache["k_rope"].at[rows, slot].set(k_rope, mode="drop")
-        new_cache["latent"], new_cache["k_rope"] = lat_cache, rope_cache
-    k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
-    new_cache["pos"] = k_pos
+    with jax.named_scope("cache_update"):
+        if spec.cfg.cache_quant:
+            lq, ls = qt.quantize_rows(latent)
+            rq, rs = qt.quantize_rows(k_rope)
+            new_cache["latent"] = cache["latent"].at[rows, slot].set(
+                lq, mode="drop")
+            new_cache["k_rope"] = cache["k_rope"].at[rows, slot].set(
+                rq, mode="drop")
+            new_cache["latent_scale"] = cache["latent_scale"].at[
+                rows, slot].set(ls, mode="drop")
+            new_cache["k_rope_scale"] = cache["k_rope_scale"].at[
+                rows, slot].set(rs, mode="drop")
+            lat_cache = qt.dequantize_rows(new_cache["latent"],
+                                           new_cache["latent_scale"], x.dtype)
+            rope_cache = qt.dequantize_rows(new_cache["k_rope"],
+                                            new_cache["k_rope_scale"],
+                                            x.dtype)
+        else:
+            lat_cache = cache["latent"].at[rows, slot].set(latent,
+                                                           mode="drop")
+            rope_cache = cache["k_rope"].at[rows, slot].set(k_rope,
+                                                            mode="drop")
+            new_cache["latent"], new_cache["k_rope"] = lat_cache, rope_cache
+        k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
+        new_cache["pos"] = k_pos
 
-    w = linear_dense_matrix(spec.wkv_b, params["wkv_b"])  # (kv_lora, H·(nope+v))
-    w = w.reshape(m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim)
-    w_uk, w_uv = w[..., : m.nope_head_dim], w[..., m.nope_head_dim:]
+    with jax.named_scope("attention"):
+        # (kv_lora, H·(nope+v))
+        w = linear_dense_matrix(spec.wkv_b, params["wkv_b"])
+        w = w.reshape(m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim)
+        w_uk, w_uv = w[..., : m.nope_head_dim], w[..., m.nope_head_dim:]
 
-    q_lat = jnp.einsum("bthn,chn->bthc", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))  # (B,C,H,kv_lora)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-    s = (jnp.einsum("bthc,bsc->bhts", q_lat, lat_cache.astype(jnp.float32))
-         + jnp.einsum("bthr,bsr->bhts", q_rope.astype(jnp.float32),
-                      rope_cache.astype(jnp.float32))) * scale
-    ok = (k_pos[:, None, :] >= 0) & (k_pos[:, None, :] <= q_pos[:, :, None])
-    s = jnp.where(ok[:, None, :, :], s, ops.NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(jnp.isnan(p), 0.0, p)  # idle slots see an empty cache
-    o_lat = jnp.einsum("bhts,bsc->bthc", p, lat_cache.astype(jnp.float32))
-    o = jnp.einsum("bthc,hcv->bthv", o_lat,
-                   w_uv.transpose(1, 0, 2).astype(jnp.float32))
-    o = o.reshape(B, C, H * m.v_head_dim).astype(x.dtype)
+        q_lat = jnp.einsum("bthn,chn->bthc", q_nope.astype(jnp.float32),
+                           w_uk.astype(jnp.float32))  # (B,C,H,kv_lora)
+        scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+        s = (jnp.einsum("bthc,bsc->bhts", q_lat,
+                        lat_cache.astype(jnp.float32))
+             + jnp.einsum("bthr,bsr->bhts", q_rope.astype(jnp.float32),
+                          rope_cache.astype(jnp.float32))) * scale
+        ok = ((k_pos[:, None, :] >= 0)
+              & (k_pos[:, None, :] <= q_pos[:, :, None]))
+        s = jnp.where(ok[:, None, :, :], s, ops.NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        p = jnp.where(jnp.isnan(p), 0.0, p)  # idle slots see an empty cache
+        o_lat = jnp.einsum("bhts,bsc->bthc", p,
+                           lat_cache.astype(jnp.float32))
+        o = jnp.einsum("bthc,hcv->bthv", o_lat,
+                       w_uv.transpose(1, 0, 2).astype(jnp.float32))
+        o = o.reshape(B, C, H * m.v_head_dim).astype(x.dtype)
     y = linear_apply(spec.out, params["out"], o)
     return parallel.shard_batch(y), new_cache
 

@@ -755,10 +755,12 @@ class LM:
         if n_tokens is None:
             n_tokens = jnp.full((B,), C, jnp.int32)
         n_tokens = jnp.asarray(n_tokens, jnp.int32)
-        x = self._embed(params, tokens)
-        if cfg.pos_embed == "learned":
-            q_pos = steps[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-            x = x + params["pos"][jnp.clip(q_pos, 0, cfg.max_seq - 1)]
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens)
+            if cfg.pos_embed == "learned":
+                q_pos = (steps[:, None]
+                         + jnp.arange(C, dtype=jnp.int32)[None, :])
+                x = x + params["pos"][jnp.clip(q_pos, 0, cfg.max_seq - 1)]
         x = parallel.shard_batch(x)
         new_cache: Params = {}
         for i, spec in enumerate(self.prefix_specs):
@@ -780,11 +782,12 @@ class LM:
             x, new_cache[f"tail_{i}"] = block_prefill(
                 spec, params[f"tail_{i}"], cache[f"tail_{i}"], x, steps,
                 n_tokens, parallel, collect_states)
-        if not all_logits:
-            last = jnp.clip(n_tokens - 1, 0, C - 1)[:, None, None]
-            x = jnp.take_along_axis(x, jnp.broadcast_to(
-                last, (B, 1, x.shape[-1])), axis=1)   # (B, 1, d)
-        logits = self._head(params, x)
+        with jax.named_scope("head"):
+            if not all_logits:
+                last = jnp.clip(n_tokens - 1, 0, C - 1)[:, None, None]
+                x = jnp.take_along_axis(x, jnp.broadcast_to(
+                    last, (B, 1, x.shape[-1])), axis=1)   # (B, 1, d)
+            logits = self._head(params, x)
         return logits, new_cache
 
     def decode_step(self, params: Params, cache: Params, tokens: jax.Array,

@@ -7,3 +7,4 @@ from repro.serve.faults import Fault, FaultError, FaultPlan  # noqa: F401
 from repro.serve.paged import PagedCache  # noqa: F401
 from repro.serve.resilience import (DEGRADE_LADDER, Backoff,  # noqa: F401
                                     Guardrail, Health, Watchdog)
+from repro.serve.tracing import Tracer  # noqa: F401

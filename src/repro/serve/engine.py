@@ -52,6 +52,7 @@ from repro.serve import resilience as rsl
 from repro.serve.config import EngineConfig, SamplingParams
 from repro.serve.faults import FaultError, FaultPlan
 from repro.serve.paged import PagedCache
+from repro.serve.tracing import Tracer
 
 
 @dataclasses.dataclass
@@ -76,6 +77,7 @@ class Request:
     degrade_path: list = dataclasses.field(default_factory=list)
     n_step_errors: int = 0     # times implicated in a step exception
     t_submit: float | None = None
+    t_admit: float | None = None   # first time it took a slot (kept on resume)
     t_first: float | None = None   # first output token (TTFT = t_first-t_submit)
     t_done: float | None = None
 
@@ -233,17 +235,16 @@ class Engine:
         self._step = step_fn if step_fn is not None else jax.jit(
             model.prefill_chunk)
         self.finished: list[Request] = []   # everything ever completed
+        # host spans and per-tick records (serve/tracing.py)
+        self.tracer = Tracer()
         self.stats = {"steps": 0, "prefill_tokens": 0, "decode_tokens": 0,
                       "prefill_time": 0.0, "decode_time": 0.0,
-                      # per-step wall times: all steps + pure-decode steps
-                      # (benchmarks reduce these to latency percentiles)
-                      "step_s": [], "decode_step_s": [],
                       # speculative rounds: drafted/accepted counts per round
                       "spec_rounds": 0, "spec_drafted": 0, "spec_accepted": 0,
                       "spec_emitted": 0,
                       # multi-tenant serving signals
                       "preemptions": 0, "prefix_hit_tokens": 0,
-                      "prompt_tokens_submitted": 0, "queue_depth": [],
+                      "prompt_tokens_submitted": 0,
                       # resilience counters (serve/resilience.py)
                       "numeric_trips": 0, "degrade_spec_off": 0,
                       "degrade_act_float": 0, "numeric_error_failures": 0,
@@ -401,7 +402,8 @@ class Engine:
             lg, new_cache = model.prefill_chunk(
                 p, cache, vt, steps, live * (k + 1),
                 all_logits=True, collect_states=True)
-            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)     # (B, Cv)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)  # (B, Cv)
             # -- accept: longest greedy-matching draft prefix (+ bonus)
             match = draft_toks == greedy[:, :k]
             n_acc = jnp.where(match.all(axis=1), k,
@@ -505,30 +507,36 @@ class Engine:
         never escapes the tick — the implicated request is failed (or the
         batch bisected until it is found) and every other active request is
         re-queued through deterministic recompute-on-resume.  The engine
-        itself cannot be crashed by a poisoned step."""
-        self._expire_deadlines()
-        self._shed_overflow()
-        self._admit()
-        self.stats["queue_depth"].append(len(self.queue))
-        if not any(s.req for s in self.slots):
-            return bool(self.queue)
-        try:
-            if self.spec_k and self._spec_eligible():
-                self._advance_spec(self.finished)
-            else:
-                self._advance(self.finished)
-        except Exception as exc:   # driver fault isolation — never the batch
-            self._step_inflight_since = None
-            self._handle_step_error(exc)
+        itself cannot be crashed by a poisoned step.
+
+        The tick and its phases are spans of ``self.tracer``; a tick that
+        runs a step leaves one record in its ring."""
+        tr = self.tracer
+        with tr.tick(self._iter, self.slots):
+            with tr.span("admit"):
+                self._expire_deadlines()
+                self._shed_overflow()
+                self._admit()
+            tr.queued(len(self.queue))
+            if not any(s.req for s in self.slots):
+                return bool(self.queue)
+            try:
+                if self.spec_k and self._spec_eligible():
+                    self._advance_spec(self.finished)
+                else:
+                    self._advance(self.finished)
+            except Exception as exc:   # fault isolation — never the batch
+                self._step_inflight_since = None
+                self._handle_step_error(exc)
+                return True
+            if self._probe and self._last_stepped:
+                # a clean step clears its participants: the culprit cannot
+                # have been among them, so the bisect narrows
+                self._cleared |= self._last_stepped
+                self._probe[0] = [u for u in self._probe[0]
+                                  if u not in self._last_stepped]
+                self._prune_probe()
             return True
-        if self._probe and self._last_stepped:
-            # a clean step clears its participants: the culprit cannot have
-            # been among them, so the bisect narrows
-            self._cleared |= self._last_stepped
-            self._probe[0] = [u for u in self._probe[0]
-                              if u not in self._last_stepped]
-            self._prune_probe()
-        return True
 
     def generate_batch(self, prompts, sampling: SamplingParams | None = None,
                        priority: int = 0) -> list[Request]:
@@ -737,7 +745,8 @@ class Engine:
 
     def sla_report(self) -> dict:
         """TTFT / TPOT percentiles per priority class, plus the multi-tenant
-        counters (preemption + prefix-hit rates, queue depth).
+        counters (preemption + prefix-hit rates, and the queue depth after
+        admission over the ring of tick records, ``self.tracer``).
 
         Every finished request contributes to its class's ``requests`` and
         ``stop_reasons`` counts, but only requests that actually produced a
@@ -771,6 +780,7 @@ class Engine:
                      "tpot_p99_s": pct(c["tpot"], 99)}
             for p, c in sorted(classes.items())}
         s = self.stats
+        depth = self.tracer.records()["queue"]
         out = {
             "classes": per_class,
             "preemptions": s["preemptions"],
@@ -780,9 +790,9 @@ class Engine:
             "prefix_hit_rate": (s["prefix_hit_tokens"]
                                 / s["prompt_tokens_submitted"]
                                 if s["prompt_tokens_submitted"] else 0.0),
-            "queue_depth_p50": pct(s["queue_depth"], 50) or 0.0,
-            "queue_depth_max": (max(s["queue_depth"])
-                                if s["queue_depth"] else 0),
+            "queue_depth_p50": (float(np.percentile(depth, 50))
+                                if len(depth) else 0.0),
+            "queue_depth_max": int(depth.max()) if len(depth) else 0,
             "resilience": self.resilience_report(),
         }
         if self._pc is not None:
@@ -1141,6 +1151,8 @@ class Engine:
                     self._finish(req, "capacity")
                     continue
             self._reset_slot(b)
+            if req.t_admit is None:
+                req.t_admit = time.perf_counter()
             slot.req = req
             slot.pos = 0
             slot.feed = feed
@@ -1296,78 +1308,81 @@ class Engine:
                 jnp.asarray(phys))
 
     def _advance(self, finished: list[Request]):
-        float_mode, partitioned = self._pick_mode()
-        n = self._schedule(float_mode)
-        if not n.any() and partitioned:
-            # the selected set had no headroom this tick; try the other one
-            float_mode = not float_mode
+        tr = self.tracer
+        with tr.span("schedule"):
+            float_mode, partitioned = self._pick_mode()
             n = self._schedule(float_mode)
-        plans = None
-        if self._pc is not None:
-            plans = self._alloc(n)
-        if not n.any():  # every active slot is out of cache headroom
+            if not n.any() and partitioned:
+                # the selected set had no headroom this tick; try the other
+                float_mode = not float_mode
+                n = self._schedule(float_mode)
+            plans = None
+            if self._pc is not None:
+                plans = self._alloc(n)
+            if not n.any():  # every active slot is out of cache headroom
+                for b, slot in enumerate(self.slots):
+                    if slot.req is not None:
+                        self._finish_slot(b, "capacity")
+                return
+            C = _bucket(int(n.max()))
+            tokens = np.zeros((self.B, C), np.int32)
+            steps = np.zeros((self.B,), np.int32)
+            sampling = [False] * self.B
+            prompt_toks = 0
+            decode_toks = 0
             for b, slot in enumerate(self.slots):
-                if slot.req is not None:
-                    self._finish_slot(b, "capacity")
-            return
-        C = _bucket(int(n.max()))
-        tokens = np.zeros((self.B, C), np.int32)
-        steps = np.zeros((self.B,), np.int32)
-        sampling = [False] * self.B
-        prompt_toks = 0
-        decode_toks = 0
-        for b, slot in enumerate(self.slots):
-            if slot.req is None or n[b] == 0:
-                continue
-            steps[b] = slot.pos
-            if slot.to_feed:
-                prompt_toks += int(n[b])
-                for i in range(n[b]):
-                    tokens[b, i] = slot.to_feed.popleft()
-                sampling[b] = len(slot.to_feed) == 0  # chunk holds prompt end
-            else:
-                decode_toks += 1
-                tokens[b, 0] = slot.req.output[-1]
-                sampling[b] = True
+                if slot.req is None or n[b] == 0:
+                    continue
+                steps[b] = slot.pos
+                if slot.to_feed:
+                    prompt_toks += int(n[b])
+                    for i in range(n[b]):
+                        tokens[b, i] = slot.to_feed.popleft()
+                    sampling[b] = len(slot.to_feed) == 0  # holds prompt end
+                else:
+                    decode_toks += 1
+                    tokens[b, 0] = slot.req.output[-1]
+                    sampling[b] = True
+            if self._pc is not None:
+                fresh, rows, lps, phys = self._pack_plans(plans)
+        tr.step(self.B, C, prompt_toks, decode_toks)
         self._iter += 1
         sched_uids = [self.slots[b].req.uid for b in range(self.B)
                       if self.slots[b].req is not None and n[b]]
         self._last_stepped = set(sched_uids)
         t0 = time.perf_counter()
         self._step_inflight_since = time.monotonic()   # watchdog window opens
-        self._poll_faults_pre(sched_uids)
-        if self._pc is not None:
-            pc = self._pc
-            fresh, rows, lps, phys = self._pack_plans(plans)
-            pstep = (self._float_paged_step() if float_mode
-                     else self._paged_step)
-            logits, pool, static = pstep(
-                self.params, tuple(pc.pool), tuple(pc.static),
-                jnp.asarray(pc.tables), fresh, rows, lps, phys,
-                jnp.asarray(tokens), jnp.asarray(steps), jnp.asarray(n))
-            pc.pool, pc.static = list(pool), list(static)
-        else:
-            step = self._float_plain_step() if float_mode else self._step
-            logits, self.cache = step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(steps), jnp.asarray(n))
-        if self.spec_k and not float_mode:
-            # keep the draft cache in sync through prefill / non-greedy
-            # iterations: replay the same chunk through the draft model.
-            # Float-mode ticks skip the replay: only rung-2 rows are
-            # scheduled then, and a rung≥1 request never drafts again.
-            _, self.draft_cache = self._step(
-                self.draft_params, self.draft_cache, jnp.asarray(tokens),
-                jnp.asarray(steps), jnp.asarray(n))
-        logits = jax.block_until_ready(logits)
+        with tr.span("dispatch"):
+            self._poll_faults_pre(sched_uids)
+            if self._pc is not None:
+                pc = self._pc
+                pstep = (self._float_paged_step() if float_mode
+                         else self._paged_step)
+                logits, pool, static = pstep(
+                    self.params, tuple(pc.pool), tuple(pc.static),
+                    jnp.asarray(pc.tables), fresh, rows, lps, phys,
+                    jnp.asarray(tokens), jnp.asarray(steps), jnp.asarray(n))
+                pc.pool, pc.static = list(pool), list(static)
+            else:
+                step = self._float_plain_step() if float_mode else self._step
+                logits, self.cache = step(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(steps), jnp.asarray(n))
+            if self.spec_k and not float_mode:
+                # keep the draft cache in sync through prefill / non-greedy
+                # iterations: replay the same chunk through the draft model.
+                # Float-mode ticks skip the replay: only rung-2 rows are
+                # scheduled then, and a rung≥1 request never drafts again.
+                _, self.draft_cache = self._step(
+                    self.draft_params, self.draft_cache, jnp.asarray(tokens),
+                    jnp.asarray(steps), jnp.asarray(n))
+        with tr.span("wait"):
+            logits = jax.block_until_ready(logits)
         self._step_inflight_since = None
         dt = time.perf_counter() - t0
         self.stats["steps"] += 1
         self.stats["prefill_tokens"] += prompt_toks
         self.stats["decode_tokens"] += decode_toks
-        self.stats["step_s"].append(dt)
-        if prompt_toks == 0 and decode_toks > 0:
-            self.stats["decode_step_s"].append(dt)
         # mixed steps: split the iteration's wall time across the phases in
         # proportion to the tokens each fed (an all-or-nothing attribution
         # inflates the minority phase's tok/s)
@@ -1376,39 +1391,44 @@ class Engine:
             self.stats["prefill_time"] += dt * prompt_toks / total
             self.stats["decode_time"] += dt * decode_toks / total
         self._note_step_done(dt)
-        ok = self._row_health(logits, sched_uids)
-        self.key, sub = jax.random.split(self.key)
-        # logits: (B, 1, V) — the model's head already projected each row's
-        # final live column only
-        greedy = np.asarray(jnp.argmax(logits[:, 0], axis=-1))  # (B,)
-        for b, slot in enumerate(self.slots):
-            if slot.req is None or n[b] == 0:
-                continue
-            if ok is not None and not bool(ok[b]):
-                # guardrail trip: requeue BEFORE advancing pos, registering
-                # the prefix or sampling — a poisoned row never contributes
-                # shared pages and never emits a garbage token; its cache
-                # rebuilds from tokens on resume at the next ladder rung
-                self._numeric_trip(b)
-                continue
-            slot.pos += int(n[b])
-            if slot.reg_at is not None and slot.pos >= slot.reg_at:
-                self._register(b, slot)
-            if not sampling[b]:
-                continue
-            if slot.req.temperature > 0:
-                kb = jax.random.fold_in(sub, b)
-                nxt = int(jax.random.categorical(
-                    kb, logits[b, 0] / slot.req.temperature))
-            else:
-                nxt = int(greedy[b])
-            self._emit(slot.req, nxt)
-            if (len(slot.req.output) >= slot.req.max_new_tokens
-                    or slot.pos >= self.max_len - 1):
-                self._finish_slot(
-                    b, "length"
-                    if len(slot.req.output) >= slot.req.max_new_tokens
-                    else "capacity")
+        with tr.span("guardrail"):
+            ok = self._row_health(logits, sched_uids)
+        with tr.span("sample"), jax.named_scope("sample"):
+            self.key, sub = jax.random.split(self.key)
+            # logits: (B, 1, V) — the model's head already projected each
+            # row's final live column only
+            greedy = np.asarray(jnp.argmax(logits[:, 0], axis=-1))  # (B,)
+        with tr.span("emit"):
+            for b, slot in enumerate(self.slots):
+                if slot.req is None or n[b] == 0:
+                    continue
+                if ok is not None and not bool(ok[b]):
+                    # guardrail trip: requeue BEFORE advancing pos,
+                    # registering the prefix or sampling — a poisoned row
+                    # never contributes shared pages and never emits a
+                    # garbage token; its cache rebuilds from tokens on
+                    # resume at the next ladder rung
+                    self._numeric_trip(b)
+                    continue
+                slot.pos += int(n[b])
+                if slot.reg_at is not None and slot.pos >= slot.reg_at:
+                    self._register(b, slot)
+                if not sampling[b]:
+                    continue
+                if slot.req.temperature > 0:
+                    with tr.span("sample"), jax.named_scope("sample"):
+                        kb = jax.random.fold_in(sub, b)
+                        nxt = int(jax.random.categorical(
+                            kb, logits[b, 0] / slot.req.temperature))
+                else:
+                    nxt = int(greedy[b])
+                self._emit(slot.req, nxt)
+                if (len(slot.req.output) >= slot.req.max_new_tokens
+                        or slot.pos >= self.max_len - 1):
+                    self._finish_slot(
+                        b, "length"
+                        if len(slot.req.output) >= slot.req.max_new_tokens
+                        else "capacity")
 
     def _emit(self, req: Request, tok: int):
         if not req.output:
@@ -1453,105 +1473,115 @@ class Engine:
         stale is ever scattered."""
         k = self.spec_k
         B = self.B
-        steps = np.zeros((B,), np.int32)
-        live = np.zeros((B,), np.int32)
-        cur = np.zeros((B,), np.int32)
-        budget = np.zeros((B,), np.int32)
-        for b, slot in enumerate(self.slots):
-            if slot.req is not None:
-                steps[b] = slot.pos
-                live[b] = 1
-                cur[b] = slot.req.output[-1]
-                # clamp the round's emission to the request budget and the
-                # cache headroom (both ≥ 1 for a scheduled decode row)
-                budget[b] = min(
-                    slot.req.max_new_tokens - len(slot.req.output),
-                    (self.max_len - 1) - slot.pos)
-        plans = None
-        if self._pc is not None:
-            plans = self._alloc_spec(live, steps, budget)
-            if plans is None:
-                self._advance(finished)   # pool pressure: plain path handles
-                return
+        tr = self.tracer
+        with tr.span("schedule"):
+            steps = np.zeros((B,), np.int32)
+            live = np.zeros((B,), np.int32)
+            cur = np.zeros((B,), np.int32)
+            budget = np.zeros((B,), np.int32)
+            for b, slot in enumerate(self.slots):
+                if slot.req is not None:
+                    steps[b] = slot.pos
+                    live[b] = 1
+                    cur[b] = slot.req.output[-1]
+                    # clamp the round's emission to the request budget and
+                    # the cache headroom (both ≥ 1 for a scheduled decode row)
+                    budget[b] = min(
+                        slot.req.max_new_tokens - len(slot.req.output),
+                        (self.max_len - 1) - slot.pos)
+            plans = None
+            if self._pc is not None:
+                plans = self._alloc_spec(live, steps, budget)
+                if plans is not None:
+                    fresh, rows, lps, phys = self._pack_plans(plans)
+        if self._pc is not None and plans is None:
+            self._advance(finished)   # pool pressure: plain path handles
+            return
+        n_live = int(live.sum())
+        tr.step(B, _bucket(k + 1), 0, n_live, spec=True)
         self._iter += 1
         sched_uids = [self.slots[b].req.uid for b in range(self.B)
                       if self.slots[b].req is not None and live[b]]
         self._last_stepped = set(sched_uids)
         t0 = time.perf_counter()
         self._step_inflight_since = time.monotonic()   # watchdog window opens
-        self._poll_faults_pre(sched_uids)
-        if self._pc is not None:
-            pc = self._pc
-            fresh, rows, lps, phys = self._pack_plans(plans)
-            (pool, static, self.draft_cache, draft_toks, greedy, n_acc,
-             n_comm, ok) = self._paged_spec(
-                self.params, self.draft_params, tuple(pc.pool),
-                tuple(pc.static), self.draft_cache, jnp.asarray(pc.tables),
-                fresh, rows, lps, phys, jnp.asarray(cur), jnp.asarray(steps),
-                jnp.asarray(live), jnp.asarray(budget))
-            pc.pool, pc.static = list(pool), list(static)
-            sync_root = pc.pool[0] if pc.pool else pc.static[0]
-        else:
-            (self.cache, self.draft_cache, draft_toks, greedy, n_acc,
-             n_comm, ok) = self._spec_round(
-                self.params, self.draft_params, self.cache, self.draft_cache,
-                jnp.asarray(cur), jnp.asarray(steps), jnp.asarray(live),
-                jnp.asarray(budget))
-            sync_root = self.cache
-        draft_toks = np.asarray(draft_toks)
-        greedy = np.asarray(greedy)
-        n_acc = np.asarray(n_acc)
-        n_comm = np.asarray(n_comm)
-        jax.block_until_ready(sync_root)
+        with tr.span("dispatch"):
+            self._poll_faults_pre(sched_uids)
+            if self._pc is not None:
+                pc = self._pc
+                (pool, static, self.draft_cache, draft_toks, greedy, n_acc,
+                 n_comm, ok) = self._paged_spec(
+                    self.params, self.draft_params, tuple(pc.pool),
+                    tuple(pc.static), self.draft_cache,
+                    jnp.asarray(pc.tables), fresh, rows, lps, phys,
+                    jnp.asarray(cur), jnp.asarray(steps), jnp.asarray(live),
+                    jnp.asarray(budget))
+                pc.pool, pc.static = list(pool), list(static)
+                sync_root = pc.pool[0] if pc.pool else pc.static[0]
+            else:
+                (self.cache, self.draft_cache, draft_toks, greedy, n_acc,
+                 n_comm, ok) = self._spec_round(
+                    self.params, self.draft_params, self.cache,
+                    self.draft_cache, jnp.asarray(cur), jnp.asarray(steps),
+                    jnp.asarray(live), jnp.asarray(budget))
+                sync_root = self.cache
+        with tr.span("wait"):
+            draft_toks = np.asarray(draft_toks)
+            greedy = np.asarray(greedy)
+            n_acc = np.asarray(n_acc)
+            n_comm = np.asarray(n_comm)
+            jax.block_until_ready(sync_root)
         self._step_inflight_since = None
         dt = time.perf_counter() - t0
         self._note_step_done(dt)
         # verify-logit health came back with the round (one fused dispatch);
         # a tripped row walks the ladder instead of emitting garbage
-        if self._guard is not None:
-            okv = self._inject_nan(np.asarray(ok).astype(bool), sched_uids)
-        else:
-            okv = np.ones((self.B,), bool)
+        with tr.span("guardrail"):
+            if self._guard is not None:
+                okv = self._inject_nan(np.asarray(ok).astype(bool),
+                                       sched_uids)
+            else:
+                okv = np.ones((self.B,), bool)
         good = live.astype(bool) & okv
-        n_live = int(live.sum())
         total_emitted = int(n_comm[good].sum())
         self.stats["steps"] += 1
         self.stats["decode_tokens"] += total_emitted
         self.stats["decode_time"] += dt
-        self.stats["step_s"].append(dt)
-        self.stats["decode_step_s"].append(dt)
         self.stats["spec_rounds"] += 1
         self.stats["spec_drafted"] += k * n_live
         self.stats["spec_accepted"] += int(np.sum(n_acc[good]))
         self.stats["spec_emitted"] += total_emitted
-        for b, slot in enumerate(self.slots):
-            if slot.req is None:
-                continue
-            if live[b] and not okv[b]:
-                # a poisoned verify round commits nothing for this row: the
-                # request requeues a rung further down the ladder and its
-                # pages (incl. the round's window) free with the slot
-                self._numeric_trip(b)
-                continue
-            # emitted tokens: the accepted draft prefix, plus the bonus
-            # (verify's next-token at the first mismatch) when it fit
-            emit = int(n_comm[b])
-            toks = [int(draft_toks[b, j])
-                    for j in range(min(emit, int(n_acc[b])))]
-            if emit == int(n_acc[b]) + 1:
-                toks.append(int(greedy[b, n_acc[b]]))
-            for t in toks:
-                self._emit(slot.req, t)
-            slot.pos += emit
-            if self._pc is not None:
-                # pages allocated for the round's window but not committed
-                self._pc.free_beyond(b, slot.pos)
-            if (len(slot.req.output) >= slot.req.max_new_tokens
-                    or slot.pos >= self.max_len - 1):
-                self._finish_slot(
-                    b, "length"
-                    if len(slot.req.output) >= slot.req.max_new_tokens
-                    else "capacity")
+        with tr.span("emit"):
+            for b, slot in enumerate(self.slots):
+                if slot.req is None:
+                    continue
+                if live[b] and not okv[b]:
+                    # a poisoned verify round commits nothing for this row:
+                    # the request requeues a rung further down the ladder
+                    # and its pages (incl. the round's window) free with
+                    # the slot
+                    self._numeric_trip(b)
+                    continue
+                # emitted tokens: the accepted draft prefix, plus the bonus
+                # (verify's next-token at the first mismatch) when it fit
+                emit = int(n_comm[b])
+                toks = [int(draft_toks[b, j])
+                        for j in range(min(emit, int(n_acc[b])))]
+                if emit == int(n_acc[b]) + 1:
+                    toks.append(int(greedy[b, n_acc[b]]))
+                for t in toks:
+                    self._emit(slot.req, t)
+                slot.pos += emit
+                if self._pc is not None:
+                    # pages allocated for the round's window but not
+                    # committed
+                    self._pc.free_beyond(b, slot.pos)
+                if (len(slot.req.output) >= slot.req.max_new_tokens
+                        or slot.pos >= self.max_len - 1):
+                    self._finish_slot(
+                        b, "length"
+                        if len(slot.req.output) >= slot.req.max_new_tokens
+                        else "capacity")
 
     def _alloc_spec(self, live, steps, budget) -> list | None:
         """Allocate each live row's speculative write window.  Returns None

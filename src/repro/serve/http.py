@@ -221,7 +221,8 @@ class Server:
         return {"throughput": eng.throughput(), "sla": eng.sla_report(),
                 "health": eng.healthz(),
                 "active": sum(1 for s in eng.slots if s.req is not None),
-                "queued": len(eng.queue)}
+                "queued": len(eng.queue),
+                "ticks": eng.tracer.summary(1000)}
 
     async def _generate(self, writer: asyncio.StreamWriter, body: bytes):
         try:

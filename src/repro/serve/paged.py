@@ -302,11 +302,12 @@ class PagedCache:
     def make_step(self):
         """Jitted paged step: reset-fresh → gather → prefill_chunk →
         scatter-write-window.  jit keys compiled variants by the bucketed
-        (chunk, fresh, triples) shapes."""
+        (chunk, fresh, triples) shapes.  Its program is named
+        ``paged_prefill_chunk``."""
         model, treedef = self.model, self.treedef
 
-        def step(params, pool, static, table, fresh, rows, lps, phys,
-                 tokens, steps, n_tokens):
+        def paged_prefill_chunk(params, pool, static, table, fresh, rows,
+                                lps, phys, tokens, steps, n_tokens):
             pool = self._reset_fresh(list(pool), fresh)
             view = self._gather(pool, table)
             cache = jax.tree.unflatten(treedef, self._merge(view, static))
@@ -317,18 +318,19 @@ class PagedCache:
             new_pool = self._scatter(pool, new_paged, rows, lps, phys)
             return logits, tuple(new_pool), tuple(new_static)
 
-        return jax.jit(step)
+        return jax.jit(paged_prefill_chunk)
 
     def make_spec_step(self, inner):
         """Wrap a fused speculative round (see ``Engine._make_spec_round``)
         with the same reset/gather/scatter plumbing.  The round's rollback
         rewinds the *view* bit-exactly, so scattering the full k+1-token
         write window writes rejected positions back with their pre-round
-        (or zero-template) bytes."""
+        (or zero-template) bytes.  Its program is named
+        ``paged_spec_round``."""
         treedef = self.treedef
 
-        def step(params, dp, pool, static, dcache, table, fresh, rows, lps,
-                 phys, cur, steps, live, budget):
+        def paged_spec_round(params, dp, pool, static, dcache, table, fresh,
+                             rows, lps, phys, cur, steps, live, budget):
             pool = self._reset_fresh(list(pool), fresh)
             view = self._gather(pool, table)
             cache = jax.tree.unflatten(treedef, self._merge(view, static))
@@ -340,7 +342,7 @@ class PagedCache:
             return (tuple(new_pool), tuple(new_static), dcache, draft_toks,
                     greedy, n_acc, n_comm, ok)
 
-        return jax.jit(step)
+        return jax.jit(paged_spec_round)
 
     def _slot_reset_impl(self, static, b):
         out = []
