@@ -333,6 +333,14 @@ def attn_cache_init(spec: AttnSpec, batch: int, max_len: int, dtype) -> Params:
     size (this is what makes long_500k decode O(window) not O(T)).  ``pos``
     is per-slot-per-row so continuous batching can mix sequence lengths.
 
+    K and V are stored lane-dense, ``(batch, S, Hkv·D)``: a slot's heads sit
+    side by side in one row (512 lanes for 8 heads of 64), and the model's
+    scan stacks the layers in front, ``(L, batch, S, Hkv·D)``.  A step
+    writes its new columns into that stacked array in place, one scatter
+    per leaf at (layer, row, slot) (``cache_write``), and attention reads
+    each layer where it lies (``ops.cache_attention``): neither copies or
+    re-lays-out a layer.
+
     With ``cfg.cache_quant`` (the ``quant.cache`` knob or legacy
     ``kv_quant``) the K/V tensors are int8 with per-(slot, head) bf16 scales
     — halves the dominant decode-memory term (beyond-paper; §Perf
@@ -344,13 +352,13 @@ def attn_cache_init(spec: AttnSpec, batch: int, max_len: int, dtype) -> Params:
         "pos": jnp.full((batch, S), -1, dtype=jnp.int32),
     }
     if spec.cfg.cache_quant:
-        c["k"] = jnp.zeros((batch, S, hkv, hd), jnp.int8)
-        c["v"] = jnp.zeros((batch, S, hkv, hd), jnp.int8)
+        c["k"] = jnp.zeros((batch, S, hkv * hd), jnp.int8)
+        c["v"] = jnp.zeros((batch, S, hkv * hd), jnp.int8)
         c["k_scale"] = jnp.zeros((batch, S, hkv), jnp.bfloat16)
         c["v_scale"] = jnp.zeros((batch, S, hkv), jnp.bfloat16)
     else:
-        c["k"] = jnp.zeros((batch, S, hkv, hd), dtype=dtype)
-        c["v"] = jnp.zeros((batch, S, hkv, hd), dtype=dtype)
+        c["k"] = jnp.zeros((batch, S, hkv * hd), dtype=dtype)
+        c["v"] = jnp.zeros((batch, S, hkv * hd), dtype=dtype)
     return c
 
 
@@ -359,9 +367,10 @@ def attn_cache_axes(spec: AttnSpec) -> Axes:
     # axis — always divisible (unlike kv_heads), so a 32k-deep cache never
     # replicates 16×.  Decode attention contracts s (sharded) → the partial
     # sum is one tiny (B,H,D) all-reduce per layer instead of a 16×-bigger
-    # resident cache.
-    a: Axes = {"k": ("batch", "kv_seq", "kv_heads", None),
-               "v": ("batch", "kv_seq", "kv_heads", None),
+    # resident cache.  The merged Hkv·D axis keeps the name "kv_heads": it
+    # divides wherever Hkv does.
+    a: Axes = {"k": ("batch", "kv_seq", "kv_heads"),
+               "v": ("batch", "kv_seq", "kv_heads"),
                "pos": ("batch", "kv_seq")}
     # cross-attention memory caches stay float (cross_memory_cache) — only
     # self-attention caches carry int8 + scales under cache_quant
@@ -369,6 +378,35 @@ def attn_cache_axes(spec: AttnSpec) -> Axes:
         a["k_scale"] = ("batch", "kv_seq", "kv_heads")
         a["v_scale"] = ("batch", "kv_seq", "kv_heads")
     return a
+
+
+def cache_layer(a: jax.Array, layer: jax.Array | None) -> jax.Array:
+    """One layer of a stacked cache leaf; ``layer=None``: ``a`` is one."""
+    if layer is None:
+        return a
+    return jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+
+
+def cache_write(a: jax.Array, layer: jax.Array | None, rows: jax.Array,
+                slot: jax.Array, vals: jax.Array) -> jax.Array:
+    """Write a chunk's columns into a sequence-axis cache leaf in place.
+
+    ``a``: (B, S, …), or the stacked (L, B, S, …) with ``layer`` naming the
+    layer; rows (B, 1), slot (B, C), vals (B, C, …).  A scatter, not a
+    ``dynamic_update_slice``: no start index is clamped, so a chunk written
+    near the end of the cache never shifts onto live entries.  A column
+    whose slot is S (out of bounds) is dropped — dead columns and ring
+    tokens overwritten later in the same chunk — so every slot a row does
+    not write keeps its content, and ``pos`` stays -1 where nothing was
+    written."""
+    idx = (rows, slot) if layer is None else (layer, rows, slot)
+    return a.at[idx].set(vals.astype(a.dtype), mode="drop")
+
+
+def _dequantize_heads(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
+    """int8 rows (…, H·D) with per-head scales (…, H) → (…, H·D) floats."""
+    heads = q.reshape(*q.shape[:-1], scale.shape[-1], -1)
+    return qt.dequantize_rows(heads, scale, dtype).reshape(q.shape)
 
 
 def _step_vec(step: jax.Array, batch: int) -> jax.Array:
@@ -379,14 +417,17 @@ def _step_vec(step: jax.Array, batch: int) -> jax.Array:
 def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
                  steps: jax.Array, n_tokens: jax.Array,
                  parallel: Parallel = NO_PARALLEL,
-                 *, memory: jax.Array | None = None) -> tuple[jax.Array, Params]:
+                 *, memory: jax.Array | None = None,
+                 layer: jax.Array | None = None) -> tuple[jax.Array, Params]:
     """Multi-token prefill at per-slot offsets (chunked-prefill step).
 
     x: (B, C, d); steps: (B,) absolute position of each row's first token;
     n_tokens: (B,) live tokens per row.  Rows are ragged: column i of row b
     is live iff ``i < n_tokens[b]``; dead columns are dropped from the cache
-    write (OOB-scatter with mode="drop") and produce garbage outputs the
-    engine discards.  C=1 with n_tokens=1 is exactly single-token decode.
+    write (``cache_write``) and produce garbage outputs the engine
+    discards.  C=1 with n_tokens=1 is exactly single-token decode.
+    ``layer``: the cache leaves are the model's stacked (L, …) cache and
+    this is the layer to write and read; the returned leaves are stacked.
     """
     cfg = spec.cfg
     hq, hkv, hd = spec.dims
@@ -399,42 +440,51 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
     if spec.cross:
         # Cross-attention reads the (precomputed) encoder memory cache as-is.
         o = ops.cache_attention(
-            q.transpose(0, 2, 1, 3), cache["k"], cache["v"], cache["pos"],
-            jnp.full((B, C), jnp.iinfo(jnp.int32).max // 2, jnp.int32))
-        y = linear_apply(spec.out, params["out"],
-                         o.transpose(0, 2, 1, 3).reshape(B, C, hq * hd))
+            q, cache["k"], cache["v"], cache_layer(cache["pos"], layer),
+            jnp.full((B, C), jnp.iinfo(jnp.int32).max // 2, jnp.int32),
+            layer=layer)
+        y = linear_apply(spec.out, params["out"], o.reshape(B, C, hq * hd))
         return parallel.shard_batch(y), cache
     if cfg.pos_embed == "rope":
         q = ops.rope(q, q_pos, cfg.rope_theta)
         k = ops.rope(k, q_pos, cfg.rope_theta)
-    S = cache["k"].shape[1]
+    S = cache["pos"].shape[-1]
     rows = jnp.arange(B)[:, None]
-    # Ring-buffer write: when the chunk is longer than the ring (C > S only
-    # happens for sliding-window layers), only a token whose slot is not
-    # re-written later in the same chunk survives — i + S >= n_tokens[b].
-    survives = valid & (offs[None, :] + S >= n_tokens[:, None])
+    if spec.window is None:
+        # a full cache holds positions [0, S); a column past it is dropped
+        survives = valid & (q_pos < S)
+    else:
+        # Ring-buffer write: when the chunk is longer than the ring, only a
+        # token whose slot is not re-written later in the same chunk
+        # survives — i + S >= n_tokens[b].
+        survives = valid & (offs[None, :] + S >= n_tokens[:, None])
     slot = jnp.where(survives, q_pos % S, S)         # S = OOB → dropped
     new_cache = dict(cache)
     with jax.named_scope("cache_update"):
-        k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
-        new_cache["pos"] = k_pos
+        new_cache["pos"] = cache_write(cache["pos"], layer, rows, slot, q_pos)
         if spec.cfg.cache_quant:
             kq, ks = qt.quantize_rows(k)
             vq, vs = qt.quantize_rows(v)
-            new_cache["k"] = cache["k"].at[rows, slot].set(kq, mode="drop")
-            new_cache["v"] = cache["v"].at[rows, slot].set(vq, mode="drop")
-            new_cache["k_scale"] = cache["k_scale"].at[rows, slot].set(
-                ks, mode="drop")
-            new_cache["v_scale"] = cache["v_scale"].at[rows, slot].set(
-                vs, mode="drop")
-            k_cache = qt.dequantize_rows(new_cache["k"], new_cache["k_scale"],
-                                         x.dtype)
-            v_cache = qt.dequantize_rows(new_cache["v"], new_cache["v_scale"],
-                                         x.dtype)
+            for name, val in (("k", kq.reshape(B, C, -1)),
+                              ("v", vq.reshape(B, C, -1)),
+                              ("k_scale", ks), ("v_scale", vs)):
+                new_cache[name] = cache_write(cache[name], layer, rows, slot,
+                                              val)
         else:
-            k_cache = cache["k"].at[rows, slot].set(k, mode="drop")
-            v_cache = cache["v"].at[rows, slot].set(v, mode="drop")
-            new_cache["k"], new_cache["v"] = k_cache, v_cache
+            new_cache["k"] = cache_write(cache["k"], layer, rows, slot,
+                                         k.reshape(B, C, hkv * hd))
+            new_cache["v"] = cache_write(cache["v"], layer, rows, slot,
+                                         v.reshape(B, C, hkv * hd))
+    k_pos = cache_layer(new_cache["pos"], layer)
+
+    def floats(c):
+        """One layer's K and V as floats, (B, S, Hkv·D) each."""
+        if not spec.cfg.cache_quant:
+            return cache_layer(c["k"], layer), cache_layer(c["v"], layer)
+        return tuple(_dequantize_heads(cache_layer(c[n], layer),
+                                       cache_layer(c[n + "_scale"], layer),
+                                       x.dtype) for n in ("k", "v"))
+
     with jax.named_scope("attention"):
         if spec.window is not None and C > 1:
             # Ring hazard: within one chunk a later token may overwrite a
@@ -442,31 +492,29 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
             # pre-write ring ‖ the chunk itself — the position mask picks
             # the right keys.
             kv_pos = jnp.where(valid, q_pos, -1)
+            k_old, v_old = floats(cache)
             if spec.cfg.cache_quant:
-                k_old = qt.dequantize_rows(cache["k"], cache["k_scale"],
-                                           x.dtype)
-                v_old = qt.dequantize_rows(cache["v"], cache["v_scale"],
-                                           x.dtype)
                 # attend to the chunk's own keys through the same int8
                 # round-trip the C=1 path reads back from the cache
                 k = qt.dequantize_rows(kq, ks, x.dtype)
                 v = qt.dequantize_rows(vq, vs, x.dtype)
-            else:
-                k_old, v_old = cache["k"], cache["v"]
             o = ops.cache_attention(
-                q.transpose(0, 2, 1, 3),
-                jnp.concatenate([k_old, k.astype(k_old.dtype)], axis=1),
-                jnp.concatenate([v_old, v.astype(v_old.dtype)], axis=1),
-                jnp.concatenate([cache["pos"], kv_pos], axis=1),
+                q,
+                jnp.concatenate([k_old, k.reshape(B, C, -1).astype(
+                    k_old.dtype)], axis=1),
+                jnp.concatenate([v_old, v.reshape(B, C, -1).astype(
+                    v_old.dtype)], axis=1),
+                jnp.concatenate([cache_layer(cache["pos"], layer), kv_pos],
+                                axis=1),
                 q_pos, window=spec.window)
-        else:
-            o = ops.cache_attention(q.transpose(0, 2, 1, 3), k_cache,
-                                    v_cache, k_pos, q_pos,
+        elif spec.cfg.cache_quant:
+            k_cache, v_cache = floats(new_cache)
+            o = ops.cache_attention(q, k_cache, v_cache, k_pos, q_pos,
                                     window=spec.window)
-    # o is (B, Hq, C, hd) — token-major flatten needs the transpose (a
-    # straight reshape is only layout-neutral at C=1)
-    y = linear_apply(spec.out, params["out"],
-                     o.transpose(0, 2, 1, 3).reshape(B, C, hq * hd))
+        else:
+            o = ops.cache_attention(q, new_cache["k"], new_cache["v"], k_pos,
+                                    q_pos, window=spec.window, layer=layer)
+    y = linear_apply(spec.out, params["out"], o.reshape(B, C, hq * hd))
     return parallel.shard_batch(y), new_cache
 
 
@@ -484,7 +532,7 @@ def cross_memory_cache(spec: AttnSpec, params: Params, memory: jax.Array) -> Par
     mkv = linear_apply(spec.qkv, params["qkv"], memory)
     _, k, v = _split_qkv(spec, mkv)
     B, S = memory.shape[0], memory.shape[1]
-    return {"k": k, "v": v,
+    return {"k": k.reshape(B, S, -1), "v": v.reshape(B, S, -1),
             "pos": jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))}
 
 
@@ -655,7 +703,8 @@ def mla_cache_axes(spec: MLASpec) -> Axes:
 
 def mla_prefill(spec: MLASpec, params: Params, cache: Params, x: jax.Array,
                 steps: jax.Array, n_tokens: jax.Array,
-                parallel: Parallel = NO_PARALLEL) -> tuple[jax.Array, Params]:
+                parallel: Parallel = NO_PARALLEL, *,
+                layer: jax.Array | None = None) -> tuple[jax.Array, Params]:
     """Latent-cache prefill/decode with absorbed up-projections.
 
     The cache holds only (kv_lora + rope) per token — the whole point of MLA.
@@ -664,7 +713,8 @@ def mla_prefill(spec: MLASpec, params: Params, cache: Params, x: jax.Array,
         score_h(t) = q_nope_h · W_uk_h · c_t  +  q_rope_h · k_rope_t
         out_h      = (Σ_t p_t · c_t) · W_uv_h
     x: (B, C, d); steps/n_tokens: (B,) per-slot offsets and live counts
-    (ragged rows, see ``attn_prefill``).  C=1 is classic decode.
+    (ragged rows, see ``attn_prefill``; ``layer`` as there).  C=1 is
+    classic decode.
     """
     m = spec.mla
     H = spec.cfg.n_heads
@@ -674,34 +724,32 @@ def mla_prefill(spec: MLASpec, params: Params, cache: Params, x: jax.Array,
     valid = offs[None, :] < n_tokens[:, None]
     q_nope, q_rope, latent, k_rope = _mla_qkv(spec, params, x, q_pos)
     rows = jnp.arange(B)[:, None]
-    S = cache["latent"].shape[1]
+    S = cache["pos"].shape[-1]
     slot = jnp.where(valid, q_pos, S)                # MLA cache is not a ring
     new_cache: Params = {}
     with jax.named_scope("cache_update"):
         if spec.cfg.cache_quant:
             lq, ls = qt.quantize_rows(latent)
             rq, rs = qt.quantize_rows(k_rope)
-            new_cache["latent"] = cache["latent"].at[rows, slot].set(
-                lq, mode="drop")
-            new_cache["k_rope"] = cache["k_rope"].at[rows, slot].set(
-                rq, mode="drop")
-            new_cache["latent_scale"] = cache["latent_scale"].at[
-                rows, slot].set(ls, mode="drop")
-            new_cache["k_rope_scale"] = cache["k_rope_scale"].at[
-                rows, slot].set(rs, mode="drop")
-            lat_cache = qt.dequantize_rows(new_cache["latent"],
-                                           new_cache["latent_scale"], x.dtype)
-            rope_cache = qt.dequantize_rows(new_cache["k_rope"],
-                                            new_cache["k_rope_scale"],
-                                            x.dtype)
+            for name, val in (("latent", lq), ("k_rope", rq),
+                              ("latent_scale", ls), ("k_rope_scale", rs)):
+                new_cache[name] = cache_write(cache[name], layer, rows, slot,
+                                              val)
+            lat_cache = qt.dequantize_rows(
+                cache_layer(new_cache["latent"], layer),
+                cache_layer(new_cache["latent_scale"], layer), x.dtype)
+            rope_cache = qt.dequantize_rows(
+                cache_layer(new_cache["k_rope"], layer),
+                cache_layer(new_cache["k_rope_scale"], layer), x.dtype)
         else:
-            lat_cache = cache["latent"].at[rows, slot].set(latent,
-                                                           mode="drop")
-            rope_cache = cache["k_rope"].at[rows, slot].set(k_rope,
-                                                            mode="drop")
-            new_cache["latent"], new_cache["k_rope"] = lat_cache, rope_cache
-        k_pos = cache["pos"].at[rows, slot].set(q_pos, mode="drop")
-        new_cache["pos"] = k_pos
+            new_cache["latent"] = cache_write(cache["latent"], layer, rows,
+                                              slot, latent)
+            new_cache["k_rope"] = cache_write(cache["k_rope"], layer, rows,
+                                              slot, k_rope)
+            lat_cache = cache_layer(new_cache["latent"], layer)
+            rope_cache = cache_layer(new_cache["k_rope"], layer)
+        new_cache["pos"] = cache_write(cache["pos"], layer, rows, slot, q_pos)
+        k_pos = cache_layer(new_cache["pos"], layer)
 
     with jax.named_scope("attention"):
         # (kv_lora, H·(nope+v))

@@ -227,8 +227,8 @@ def block_cache_init(spec: BlockSpec, batch: int, max_len: int, dtype) -> Params
         # placeholder; filled by cross_memory_cache at prefill/encode time
         hq, hkv, hd = spec.cross.dims
         n_mem = 1  # overwritten with real memory length by encdec
-        c["cross"] = {"k": jnp.zeros((batch, n_mem, hkv, hd), dtype),
-                      "v": jnp.zeros((batch, n_mem, hkv, hd), dtype),
+        c["cross"] = {"k": jnp.zeros((batch, n_mem, hkv * hd), dtype),
+                      "v": jnp.zeros((batch, n_mem, hkv * hd), dtype),
                       "pos": jnp.zeros((n_mem,), jnp.int32)}
     return c
 
@@ -249,22 +249,28 @@ def block_cache_axes(spec: BlockSpec) -> dict:
 
 def block_prefill(spec: BlockSpec, params: Params, cache: Params, x: jax.Array,
                   steps: jax.Array, n_tokens: jax.Array, parallel: Parallel,
-                  collect: bool = False) -> tuple[jax.Array, Params]:
+                  collect: bool = False, *, layer: jax.Array | None = None
+                  ) -> tuple[jax.Array, Params]:
     """Multi-token cached step.  x: (B, C, d); steps/n_tokens: (B,) per-slot
     offsets and live token counts (ragged rows — see the mixer prefills).
     ``collect=True`` makes the recurrent mixers (SSD / RG-LRU) return
     per-token state snapshots in their cache for speculative rollback (the
-    KV families rewind by position and need no snapshots)."""
+    KV families rewind by position and need no snapshots).
+
+    ``layer``: the leaves whose axes carry ``kv_seq`` (the attention and MLA
+    caches) are the model's stacked (L, …) leaves and ``layer`` is this
+    block's index in them; they are written in place and returned stacked.
+    The other leaves (recurrent state) are this layer's own."""
     h = L.norm_apply(params["norm1"], x, spec.norm)
     new_cache = dict(cache)
     if spec.kind in ("attn", "local_attn"):
         m, new_cache["mixer"] = L.attn_prefill(
             spec.mixer, params["mixer"], cache["mixer"], h, steps, n_tokens,
-            parallel)
+            parallel, layer=layer)
     elif spec.kind == "mla":
         m, new_cache["mixer"] = L.mla_prefill(
             spec.mixer, params["mixer"], cache["mixer"], h, steps, n_tokens,
-            parallel)
+            parallel, layer=layer)
     elif spec.kind == "rglru":
         m, new_cache["mixer"] = rglru_prefill(
             spec.mixer, params["mixer"], cache["mixer"], h, steps, n_tokens,
@@ -277,7 +283,7 @@ def block_prefill(spec: BlockSpec, params: Params, cache: Params, x: jax.Array,
     if spec.cross is not None:
         h = L.norm_apply(params["norm_x"], x, spec.norm)
         m, _ = L.attn_prefill(spec.cross, params["cross"], cache["cross"], h,
-                              steps, n_tokens, parallel)
+                              steps, n_tokens, parallel, layer=layer)
         x = x + m
     if spec.ffn_kind == "moe":
         h = L.norm_apply(params["norm2"], x, spec.norm)
@@ -387,6 +393,35 @@ def _truncate_tree(tree, plan: dict, path: str = ""):
     return tree
 
 
+def _is_axes(t) -> bool:
+    return isinstance(t, tuple)
+
+
+def _split(tree: Params, mask) -> tuple[Params, Params]:
+    """Split a nested cache dict by a bool tree of the same nesting into
+    (leaves where True, the rest), both keeping the nesting.  Keys the mask
+    does not name (speculative state snapshots) go to the rest."""
+    yes: Params = {}
+    no: Params = {}
+    for k, v in tree.items():
+        m = mask.get(k, False)
+        if isinstance(v, dict):
+            yes[k], no[k] = _split(v, m if isinstance(m, dict) else {})
+        elif m is True:
+            yes[k] = v
+        else:
+            no[k] = v
+    return yes, no
+
+
+def _join(a: Params, b: Params) -> Params:
+    """Inverse of ``_split``."""
+    out = dict(b)
+    for k, v in a.items():
+        out[k] = _join(v, b.get(k, {})) if isinstance(v, dict) else v
+    return out
+
+
 # ---------------------------------------------------------------------------
 # The language model.
 # ---------------------------------------------------------------------------
@@ -480,7 +515,7 @@ class LM:
                    for j, spec in enumerate(self.cycle_specs)}
             a["cycles"] = jax.tree.map(
                 lambda ax: ("layers",) + ax, cyc,
-                is_leaf=lambda t: isinstance(t, tuple))
+                is_leaf=_is_axes)
         for i, spec in enumerate(self.tail_specs):
             a[f"tail_{i}"] = block_axes(spec)
         if cfg.mtp:
@@ -720,7 +755,7 @@ class LM:
                    for j, spec in enumerate(self.cycle_specs)}
             a["cycles"] = jax.tree.map(
                 lambda ax: ("layers",) + ax, cyc,
-                is_leaf=lambda t: isinstance(t, tuple))
+                is_leaf=_is_axes)
         for i, spec in enumerate(self.tail_specs):
             a[f"tail_{i}"] = block_cache_axes(spec)
         return a
@@ -768,16 +803,28 @@ class LM:
                 spec, params[f"pre_{i}"], cache[f"pre_{i}"], x, steps,
                 n_tokens, parallel, collect_states)
         if self.n_cycles:
-            def cycle(x, pc):
+            # The sequence-axis leaves ride the loop's carry, stacked, and
+            # each layer writes its columns into them in place; the small
+            # per-row state leaves are sliced in and stacked out per layer.
+            seq = jax.tree.map(lambda ax: "kv_seq" in ax,
+                               self.cache_axes()["cycles"], is_leaf=_is_axes)
+            stacked, per_layer = _split(cache["cycles"], seq)
+
+            def cycle(carry, pc):
+                x, stacked, i = carry
                 p, c = pc
-                new_c = {}
+                stacked, out = dict(stacked), {}
                 for j, spec in enumerate(self.cycle_specs):
-                    x, new_c[f"blk_{j}"] = block_prefill(
-                        spec, p[f"blk_{j}"], c[f"blk_{j}"], x, steps,
-                        n_tokens, parallel, collect_states)
-                return x, new_c
-            x, new_cache["cycles"] = jax.lax.scan(
-                cycle, x, (params["cycles"], cache["cycles"]))
+                    b = f"blk_{j}"
+                    x, new = block_prefill(
+                        spec, p[b], _join(stacked[b], c[b]), x, steps,
+                        n_tokens, parallel, collect_states, layer=i)
+                    stacked[b], out[b] = _split(new, seq[b])
+                return (x, stacked, i + 1), out
+            (x, stacked, _), per_layer = jax.lax.scan(
+                cycle, (x, stacked, jnp.int32(0)),
+                (params["cycles"], per_layer))
+            new_cache["cycles"] = _join(stacked, per_layer)
         for i, spec in enumerate(self.tail_specs):
             x, new_cache[f"tail_{i}"] = block_prefill(
                 spec, params[f"tail_{i}"], cache[f"tail_{i}"], x, steps,

@@ -11,7 +11,10 @@ fixture, never at import: only the worker that runs this file loads the
 TPU library.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 import pytest
 
@@ -19,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs, quant as qt
+from repro.core.structures import StructureConfig
 from repro.kernels import ops
 from repro.models import build_model
 
@@ -120,3 +124,97 @@ def test_llama7b_prefill_chunk_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < 16e9, mem
+
+
+# -- the decode step moves no cache it does not write --------------------------
+
+_INST = re.compile(
+    r"\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\](?:\{[^}]*\})? ([\w\-]+)\(")
+
+
+def _data_moves(hlo: str):
+    """(in the entry computation?, name, shape) of every executed
+    instruction that copies, transposes or dynamic-update-slices — a
+    fusion counts as the op its fused computation ends in."""
+    comps, name, entry = {}, None, None
+    for line in hlo.splitlines():
+        if name is None:
+            m = re.match(r"(ENTRY )?%([\w.\-]+) ", line)
+            if m and line.rstrip().endswith("{"):
+                name = m.group(2)
+                comps[name] = []
+                entry = name if m.group(1) else entry
+        elif line.strip() == "}":
+            name = None
+        else:
+            comps[name].append(line)
+    root, fused = {}, set()
+    for cname, lines in comps.items():
+        for line in lines:
+            m = _INST.match(line)
+            if m and m.group(1):
+                root[cname] = m.group(4)
+            if " fusion(" in line:
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    out = []
+    for cname, lines in comps.items():
+        if cname in fused:
+            continue
+        for line in lines:
+            m = _INST.match(line)
+            if not m:
+                continue
+            op = m.group(4)
+            if op == "fusion":
+                op = root.get(re.search(r"calls=%([\w.\-]+)", line).group(1))
+            if op in ("copy", "transpose", "dynamic-update-slice"):
+                out.append((cname == entry, m.group(2),
+                            tuple(int(d) for d in m.group(3).split(",") if d)))
+    return out
+
+
+GRANITE = {"blast50": StructureConfig(kind="blast", b=16, keep_ratio=0.5),
+           "dense": StructureConfig(kind="dense")}
+
+
+@pytest.mark.parametrize("C", [1, 256], ids=["decode", "prefill"])
+@pytest.mark.parametrize("structure", sorted(GRANITE))
+def test_granite_step_moves_no_whole_layer_of_cache(one_chip, structure, C):
+    """The serving step of the benchmark's Granite-3.0-2B configurations
+    (12 slots, max_len 2048, pre-stacked params, bf16) compiled for a v5e:
+    inside the layer loop no copy, transpose or dynamic-update-slice makes
+    a whole layer of K or V, or the stacked cache; outside it at most one
+    copy of each stacked K and V (the undonated entry); and the output is
+    the cache at its own size, nothing padded or doubled."""
+    cfg = dataclasses.replace(
+        configs.get("granite-3-2b"), structure=GRANITE[structure],
+        structure_ffn=None, param_dtype="bfloat16", compute_dtype="bfloat16")
+    model = build_model(cfg)
+    B, S, L = 12, 2048, cfg.n_layers
+    HD = cfg.n_kv_heads * cfg.head_dim_
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = place(jax.eval_shape(
+        lambda k: model.prestack_params(model.init(k)),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(B, S)))
+    tokens = _sds((B, C), jnp.int32, one_chip)
+    steps = _sds((B,), jnp.int32, one_chip)
+    compiled = jax.jit(model.prefill_chunk).lower(
+        params, cache, tokens, steps, steps).compile()
+
+    def kv(shape):  # a layer of K or V or more, in any of its layouts
+        return (math.prod(shape) >= B * S * HD
+                and (shape[-1:] == (HD,) or shape[-2:] == (cfg.n_kv_heads,
+                                                         cfg.head_dim_))
+                and bool({S, B * S, L * B * S} & set(shape)))
+
+    moves = [m for m in _data_moves(compiled.as_text()) if kv(m[2])]
+    in_loop = [m for m in moves if not m[0]]
+    assert not in_loop, in_loop
+    assert len(moves) <= 2, moves
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    logits_bytes = B * cfg.vocab * jnp.dtype(cfg.compute_dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= cache_bytes + 2 * logits_bytes, mem
