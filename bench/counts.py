@@ -4,16 +4,67 @@ Everything here is a function of the configuration file and of the token
 positions a step processes, never of the compiled program: a change that
 removes a copy or swaps a kernel is judged against the same work.
 
-FLOPs count a multiply-add as two.  A BLAST linear with b blocks and rank r
-does (m + n + b^2) r multiply-adds per token (paper Alg. 1), a dense one m n.
-Attention of one query at context c does 2 hq hd c multiply-adds per layer
-(scores and weighted values).  The vocabulary head counts only for tokens
-that are sampled; padding columns and rows never count.
+What a family's layers hold lives in its own module,
+``bench/families/<family>.py``, found by the configuration's ``family``.
+Every family module defines:
+
+  program_config(cfg, reduced)   the program's ArchConfig and the
+      dictionary the run uses; refuses a program that differs from the file
+  param_bytes(cfg)               stored weight bytes
+  head_flops(cfg)                FLOPs of the vocabulary head, one token
+  span_flops(cfg, start, stop)   FLOPs of feeding positions start..stop-1
+      of one sequence, head excluded
+  decode_step(cfg, contexts, peak)
+      flops, bytes, least time and its bound of one decode step whose live
+      rows hold ``contexts`` tokens each after it
+  describe(cfg)                  the counts a reader of PERF.md wants
+
+The functions below dispatch to it.  What follows them is shared by every
+family whose linears are dense or BLAST: a BLAST linear with b blocks and
+rank r does (m + n + b^2) r multiply-adds per token (paper Alg. 1), a dense
+one m n.
 """
 
 from __future__ import annotations
 
+from pathlib import Path
+
+from bench import ROOT, load
+
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
+
+
+def family(cfg: dict, root: Path = ROOT):
+    """The module of the configuration's family under ``root``."""
+    return load("families", cfg["family"], root)
+
+
+def param_bytes(cfg: dict) -> int:
+    return family(cfg).param_bytes(cfg)
+
+
+def head_flops(cfg: dict) -> int:
+    return family(cfg).head_flops(cfg)
+
+
+def span_flops(cfg: dict, start: int, stop: int) -> int:
+    return family(cfg).span_flops(cfg, start, stop)
+
+
+def decode_step(cfg: dict, contexts: list[int], peak: dict) -> dict:
+    return family(cfg).decode_step(cfg, contexts, peak)
+
+
+def describe(cfg: dict) -> dict:
+    return family(cfg).describe(cfg)
+
+
+def __getattr__(name: str):
+    """``dense_gqa``'s own terms, under the names they had here."""
+    if name in ("kv_bytes_per_token", "linear_flops_per_token",
+                "attn_flops_per_context"):
+        return getattr(load("families", "dense_gqa"), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def blocks(d_in: int, d_out: int, b: int) -> int:
@@ -31,15 +82,6 @@ def blast_rank(d_in: int, d_out: int, b: int, keep: float, align: int) -> int:
     if align > 1 and r >= 2 * align:
         r = (r // align) * align
     return max(r, 1)
-
-
-def linear_shapes(cfg: dict) -> list[tuple[str, int, int]]:
-    """(name, d_in, d_out) of every linear in one layer."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    hq, hkv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                   cfg["head_dim"])
-    return [("qkv", d, (hq + 2 * hkv) * hd), ("out", hq * hd, d),
-            ("gate", d, ff), ("up", d, ff), ("down", ff, d)]
 
 
 def linear_factors(cfg: dict, d_in: int, d_out: int) -> dict:
@@ -61,74 +103,3 @@ def linear_params(cfg: dict, d_in: int, d_out: int) -> int:
     if f["kind"] == "dense":
         return d_in * d_out
     return (d_in + d_out + f["b"] ** 2) * f["r"]
-
-
-def param_count(cfg: dict) -> int:
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    per_layer = sum(linear_params(cfg, i, o) for _, i, o in linear_shapes(cfg))
-    per_layer += 2 * d                                   # two norm gains
-    n = L * per_layer + cfg["vocab_size"] * d + d        # embed, final norm
-    if not cfg["tie_word_embeddings"]:
-        n += cfg["vocab_size"] * d
-    return n
-
-
-def param_bytes(cfg: dict) -> int:
-    return param_count(cfg) * DTYPE_BYTES[cfg["param_dtype"]]
-
-
-def kv_bytes_per_token(cfg: dict) -> int:
-    """Keys and values of one token in every layer's cache."""
-    return (2 * cfg["num_key_value_heads"] * cfg["head_dim"]
-            * cfg["num_hidden_layers"] * DTYPE_BYTES[cfg["compute_dtype"]])
-
-
-def linear_flops_per_token(cfg: dict) -> int:
-    return 2 * cfg["num_hidden_layers"] * sum(
-        linear_params(cfg, i, o) for _, i, o in linear_shapes(cfg))
-
-
-def attn_flops_per_context(cfg: dict) -> int:
-    """Attention FLOPs of one query per token of context, all layers."""
-    return (4 * cfg["num_attention_heads"] * cfg["head_dim"]
-            * cfg["num_hidden_layers"])
-
-
-def head_flops(cfg: dict) -> int:
-    return 2 * cfg["hidden_size"] * cfg["vocab_size"]
-
-
-def span_flops(cfg: dict, start: int, stop: int) -> int:
-    """FLOPs of feeding the tokens at positions start..stop-1 of one
-    sequence (token at position p attends to p + 1 tokens), head excluded."""
-    n = stop - start
-    if n <= 0:
-        return 0
-    ctx = (start + 1 + stop) * n // 2                    # sum of p + 1
-    return n * linear_flops_per_token(cfg) + ctx * attn_flops_per_context(cfg)
-
-
-def decode_step(cfg: dict, contexts: list[int], peak: dict) -> dict:
-    """Least time of one decode step whose live rows hold ``contexts``
-    tokens each after the step (the new token included).
-
-    Needed bytes: every weight once at its stored dtype, the keys and
-    values of each live row's context, and those written for the new
-    tokens.  Returns flops, bytes, the least time and which peak bounds it.
-    """
-    flops = sum(span_flops(cfg, c - 1, c) + head_flops(cfg) for c in contexts)
-    kv = kv_bytes_per_token(cfg)
-    nbytes = param_bytes(cfg) + kv * sum(contexts) + kv * len(contexts)
-    t_flops = flops / peak["bf16_flops_per_s"]
-    t_bytes = nbytes / peak["hbm_bytes_per_s"]
-    return {"flops": flops, "bytes": nbytes, "least_s": max(t_flops, t_bytes),
-            "bound": "bytes" if t_bytes >= t_flops else "flops"}
-
-
-def describe(cfg: dict) -> dict:
-    """The counts a reader of PERF.md wants, in one dictionary."""
-    return {"params": param_count(cfg), "param_bytes": param_bytes(cfg),
-            "kv_bytes_per_token": kv_bytes_per_token(cfg),
-            "linear_flops_per_token": linear_flops_per_token(cfg),
-            "linears": {n: linear_factors(cfg, i, o)
-                        for n, i, o in linear_shapes(cfg)}}

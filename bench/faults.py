@@ -11,11 +11,16 @@ from __future__ import annotations
 
 def plant(engine, kind: str) -> None:
     if kind == "stale_cache":
+        import jax
+        import jax.numpy as jnp
         step = engine._step
 
         def stale(params, cache, *args):
+            # a copy taken before the step: a step may consume (donate) the
+            # cache it is given
+            before = jax.tree.map(jnp.copy, cache)
             logits, _ = step(params, cache, *args)
-            return logits, cache
+            return logits, before
         engine._step = stale
     elif kind == "alter_token":
         emit, count = engine._emit, [0]

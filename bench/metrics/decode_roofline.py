@@ -2,12 +2,11 @@
 device time of the serving step's program in them (%).
 
 A step's least time is the larger of its FLOPs over the peak FLOP/s and its
-needed bytes over the peak bytes/s (``bench/counts.py::decode_step``):
+needed bytes over the peak bytes/s, as the configuration's family counts
+them (``decode_step`` of ``bench/families/<family>.py``; for ``dense_gqa``
 every weight once, the keys and values of each live row's context, and
-those written for the new tokens.  The device time is that of the step
+those written for the new tokens).  The device time is that of the step
 program's runs inside the benchmark's ``tick`` span (``bench/trace.py``)."""
-
-from bench import counts
 
 
 def read(run):
@@ -18,7 +17,7 @@ def read(run):
     least = device = 0.0
     bounds = set()
     for i, t in step.items():
-        c = counts.decode_step(run.cfg, ctx[i], run.peak)
+        c = run.family.decode_step(run.cfg, ctx[i], run.peak)
         least += c["least_s"]
         device += t
         bounds.add(c["bound"])

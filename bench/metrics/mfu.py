@@ -1,9 +1,10 @@
 """mfu: model FLOPs of the prompt and output tokens the window processed,
 over the window's seconds times the chips' peak bf16 FLOP/s (%).
 
-FLOPs are counted from shapes at each token's real context length
-(``bench/counts.py``): BLAST at its structured cost, dense at 2 m n per
-token, the vocabulary head once per sampled token; padding never counts."""
+FLOPs are counted from shapes at each token's real context length by the
+configuration's family (``span_flops`` and ``head_flops`` of
+``bench/families/<family>.py``): linears at their structured cost, the
+vocabulary head once per sampled token; padding never counts."""
 
 
 def read(run):

@@ -13,10 +13,12 @@ entry in BENCHMARK.json gives.  The run:
    run with exit code 3 and no result), and keeps JAX's persistent
    compilation cache at ``$JAX_COMPILATION_CACHE_DIR`` or else at
    ``<checkout>/.jax_cache``;
-2. builds the program's model and engine at the configuration's sizes, with
-   the weights made on the chip in one jitted call from ``--seed``, warms
-   every step width the mix can use, and drives a short burst of requests
-   through the engine so that nothing compiles in the window;
+2. builds the program's model and engine at the configuration's sizes, as
+   its family (``bench/families/<family>.py``) checks them against the
+   program, with the weights made on the chip in one jitted call from
+   ``--seed``, warms every step width the mix can use, and drives a short
+   burst of requests through the engine so that nothing compiles in the
+   window;
 3. replays the mix open-loop (``traffic.py``): requests are submitted
    between ``Engine.tick`` calls once due, and every emitted token is
    stamped when the tick that produced it returns;
@@ -46,7 +48,6 @@ T_PROCESS = time.time()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -67,7 +68,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import counts, traffic  # noqa: E402
+from bench import counts, load, traffic  # noqa: E402
 from bench import trace as trace_reduce  # noqa: E402
 
 TRACE_S = 8.0          # traced span: the last seconds of the window
@@ -95,6 +96,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     limits: dict
+    root: Path = ROOT       # the checkout whose bench/ holds the cell's files
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
@@ -112,7 +114,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     limits = json.loads(
         (root / "bench" / "limits" / f"{workload}.json").read_text())
     return Cell(workload, w["chips"], cfg, mix, mine(bench["end_to_end"]),
-                mine(bench["per_layer"]), limits)
+                mine(bench["per_layer"]), limits, root)
 
 
 def find_chips(n: int):
@@ -147,53 +149,9 @@ def seed_key(seed: int):
 
 
 def program_config(cfg: dict, reduced: bool = False):
-    """The program's ArchConfig for a configuration file; checks that every
-    size and option the file states is what the program will run."""
-    import dataclasses
-    from repro import configs
-    from repro.core.structures import StructureConfig
-
-    st = cfg["structure"]
-    structure = (StructureConfig(kind="dense") if st["kind"] == "dense" else
-                 StructureConfig(kind=st["kind"], b=st["b"],
-                                 keep_ratio=st["keep_ratio"]))
-    arch = dataclasses.replace(
-        configs.get(cfg["arch"]), structure=structure, structure_ffn=None,
-        param_dtype=cfg["param_dtype"], compute_dtype=cfg["compute_dtype"])
-    if reduced:
-        arch = arch.reduced(param_dtype=cfg["param_dtype"],
-                            compute_dtype=cfg["compute_dtype"])
-        return arch, reduced_config(cfg, arch)
-    have = {"hidden_size": arch.d_model, "intermediate_size": arch.d_ff,
-            "num_hidden_layers": arch.n_layers,
-            "num_attention_heads": arch.n_heads,
-            "num_key_value_heads": arch.n_kv_heads,
-            "head_dim": arch.head_dim_, "vocab_size": arch.vocab,
-            "tie_word_embeddings": arch.tie_embeddings,
-            "rope_theta": arch.rope_theta}
-    wrong = {k: (cfg[k], v) for k, v in have.items() if cfg[k] != v}
-    plain = (arch.pattern == ("attn",) and arch.ffn_kind == "swiglu"
-             and arch.norm == "rmsnorm" and arch.pos_embed == "rope"
-             and not arch.qkv_bias and not arch.embed_scale
-             and not arch.logit_softcap and arch.moe is None)
-    if wrong or not plain:
-        raise SystemExit(f"{cfg['name']}: the program's {cfg['arch']} differs "
-                         f"from the configuration file: {wrong or 'layers'}")
-    return arch, cfg
-
-
-def reduced_config(cfg: dict, arch) -> dict:
-    """The configuration dictionary of a ``.reduced()`` program config (CPU
-    rehearsals of the harness only)."""
-    out = dict(cfg)
-    out.update(hidden_size=arch.d_model, intermediate_size=arch.d_ff,
-               num_hidden_layers=arch.n_layers, num_attention_heads=arch.n_heads,
-               num_key_value_heads=arch.n_kv_heads, head_dim=arch.head_dim_,
-               vocab_size=arch.vocab,
-               attention_multiplier=1.0 / math.sqrt(arch.head_dim_))
-    if cfg["structure"]["kind"] != "dense":
-        out["structure"] = dict(cfg["structure"], b=arch.structure.b)
-    return out
+    """The program's ArchConfig and the run's configuration dictionary, from
+    the configuration's family (``bench/families/<family>.py``)."""
+    return counts.family(cfg).program_config(cfg, reduced)
 
 
 def build_engine(arch, mix: dict, key, control: str | None):
@@ -224,8 +182,10 @@ def warm_up(engine) -> None:
     B, C = engine.B, 1
     zeros = jnp.zeros((B,), jnp.int32)
     while True:
-        logits, _ = engine._step(engine.params, engine.cache,
-                                 jnp.zeros((B, C), jnp.int32), zeros, zeros)
+        # keep the returned cache: a step may consume (donate) its input
+        logits, engine.cache = engine._step(
+            engine.params, engine.cache, jnp.zeros((B, C), jnp.int32), zeros,
+            zeros)
         jax.block_until_ready(logits)
         if C >= engine.chunk:
             break
@@ -290,15 +250,16 @@ class Recorder:
     """Per-request and per-tick records, read from the engine after each
     tick returns (the engine stamps only the first token itself)."""
 
-    def __init__(self, engine, cfg: dict):
+    def __init__(self, engine, cfg: dict, family=None):
         self.engine, self.cfg = engine, cfg
+        self.family = family or counts.family(cfg)
         self.gc = []                       # (start, seconds) of collections
         self._gc_at = None
         gc.callbacks.append(self._gc)
         self.reqs: dict[int, ReqRec] = {}
         self.ticks: list[Tick] = []
         self.n_finished = len(engine.finished)
-        self.head = counts.head_flops(cfg)
+        self.head = self.family.head_flops(cfg)
 
     def _gc(self, phase, info) -> None:
         if phase == "start":
@@ -332,7 +293,8 @@ class Recorder:
                 rec.admit = end
             new = len(rec.req.output) - len(rec.stamps)
             rec.stamps.extend([end] * new)
-            flops += counts.span_flops(self.cfg, rec.fed, fed) + new * self.head
+            flops += (self.family.span_flops(self.cfg, rec.fed, fed)
+                      + new * self.head)
             if fed > rec.fed:
                 contexts.append(fed)
                 most = max(most, fed - rec.fed)
@@ -473,10 +435,6 @@ def end_to_end(run) -> dict:
         ttft = [((r.stamps[0] if r.stamps else w.stop) - r.due) * 1e3
                 for r in run.due_in_window()]
         out["ttft_p50_ms"] = nearest_rank(ttft, 0.50)
-    if "itl_p95_ms" in names:
-        gaps = [(b - a) * 1e3 for r in recs
-                for a, b in zip(r.stamps, r.stamps[1:]) if in_window(b)]
-        out["itl_p95_ms"] = nearest_rank(gaps, 0.95)
     out["setup_s"] = w.setup_s
     return out
 
@@ -500,24 +458,19 @@ ERRORS = ("error", "numeric_error", "capacity", "shed", "deadline")
 # ---------------------------------------------------------------------------
 
 
-def reader(name: str):
+def reader(name: str, root: Path = ROOT):
     """``bench/metrics/<name>.py``, or the file of the quantity the name
     splits from (``decode_tick_ms.online`` -> ``decode_tick_ms.py``)."""
     for stem in (name, name.split(".")[0]):
-        path = BENCH / "metrics" / f"{stem}.py"
-        if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+        if (root / "bench" / "metrics" / f"{stem}.py").exists():
+            return load("metrics", stem, root).read
     raise SystemExit(f"no reader for per-layer metric {name!r}")
 
 
 def per_layer(run) -> dict:
     out = {}
     for m in run.cell.per_layer:
-        v = reader(m["name"])(run)
+        v = reader(m["name"], run.cell.root)(run)
         if v is not None:
             out[m["name"]] = v
     return out
@@ -550,14 +503,6 @@ def sample(run, seed: int) -> list[ReqRec]:
     return picked
 
 
-def load_reference(family: str):
-    path = BENCH / "reference" / f"{family}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_ref_{family}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def check(run, seed: int, key) -> dict:
     """The numbers compared, each beside its limit, over every served token
     of the sample: ``mean_gap`` and ``gap_p99``, the mean and the 99th
@@ -568,7 +513,7 @@ def check(run, seed: int, key) -> dict:
     (see PERF.md)."""
     picked = sample(run, seed)
     seqs = [(r.req.prompt, r.req.output) for r in picked]
-    ref = load_reference(run.cfg["family"])
+    ref = load("reference", run.cfg["family"], run.cell.root)
     gaps = ref.served_gaps(run.cfg, key, seqs,
                            length=run.cell.mix["engine"]["max_len"],
                            rows=SAMPLE_ROWS)
@@ -606,6 +551,11 @@ class Run:
 
     quantile = staticmethod(nearest_rank)
 
+    @property
+    def family(self):
+        """The counts of the cell's family (``bench/families/``)."""
+        return self.rec.family
+
     def in_window(self, t: float) -> bool:
         return self.win.open < t <= self.win.close
 
@@ -633,7 +583,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         devices = find_chips(cell.chips)
         enable_compile_cache()
     compiles = CompileCount()
-    arch, cfg = program_config(cell.cfg, reduced)
+    family = counts.family(cell.cfg, cell.root)
+    arch, cfg = family.program_config(cell.cfg, reduced)
     key = seed_key(seed)
     engine = build_engine(arch, cell.mix, key, control)
     warm_up(engine)
@@ -642,7 +593,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         faults.plant(engine, fault)
     say(f"set-up: {compiles.n} programs compiled or fetched "
         f"({compiles.compile_s:.1f} s compiling)")
-    rec = Recorder(engine, cfg)
+    rec = Recorder(engine, cfg, family)
     if trace:
         annotate(engine)
     win = drive(engine, rec, cell.mix, seed, seconds, trace, compiles)
@@ -692,6 +643,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             f"{sum(ttft) / len(ttft):.1f} ms, p50 "
             f"{nearest_rank(ttft, 0.5):.1f} ms, p80 "
             f"{nearest_rank(ttft, 0.8):.1f} ms, max {ttft[-1]:.1f} ms")
+        itl = sorted((b - a) * 1e3 for r in rec.reqs.values()
+                     for a, b in zip(r.stamps, r.stamps[1:])
+                     if run.in_window(b))
+        if itl:
+            say(f"itl over {len(itl)} gaps: " + ", ".join(
+                f"p{round(100 * q)} {nearest_rank(itl, q):.1f}"
+                for q in (0.5, 0.9, 0.95, 0.99))
+                + f" ms, max {itl[-1]:.1f} ms; "
+                f"{sum(g > 100 for g in itl)} over 100 ms")
     if trace:
         dec = {t.index: t.contexts for t in rec.ticks
                if t.index is not None and t.contexts}

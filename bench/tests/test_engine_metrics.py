@@ -83,3 +83,11 @@ def test_nothing_from_a_program_without_them(run):
         due=0.0, req=types.SimpleNamespace(t_submit=0.0))]
     for name in NAMES:
         assert _read(bare, name) is None
+
+
+def test_itl_p95_spans_at_least_one_tick(run):
+    """Consecutive tokens of a row are stamped at the ends of two ticks, so
+    no gap is shorter than the window's shortest tick."""
+    ticks = [t.end - t.start for t in run.rec.ticks if run.in_window(t.end)]
+    v = _read(run, "itl_p95_ms.online")
+    assert 1e3 * min(ticks) <= v <= 1e3 * (run.win.stop - run.win.open)

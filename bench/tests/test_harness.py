@@ -81,3 +81,25 @@ def test_benchmark_json_names_existing_files():
     from bench import run as R
     for m in bench["per_layer"]:
         assert callable(R.reader(m["name"]))
+
+
+@pytest.mark.parametrize("fault,correct", [(None, True),
+                                           ("stale_cache", False)])
+def test_step_that_donates_its_cache(tiny_cell, monkeypatch, fault, correct):
+    """A program may compile its step to consume (donate) the cache it is
+    given: warm-up keeps the cache each step returns, and the stale-cache
+    fault returns a copy taken before the step."""
+    import jax
+    from bench import run as R
+
+    build = R.build_engine
+
+    def donating(*a, **k):
+        engine = build(*a, **k)
+        engine._step = jax.jit(engine.model.prefill_chunk,
+                               donate_argnums=(1,))
+        return engine
+
+    monkeypatch.setattr(R, "build_engine", donating)
+    res = _run(tiny_cell(), 2**31 + 12, fault=fault)
+    assert res["correct"] is correct
