@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs + the paper's own models.
+"""Architecture registry: the assigned archs + the paper's own models.
 
 Every config is importable and selectable via ``--arch <id>``; the BLAST
 structure (keep=0.5, b=16 — the paper's Llama-7B headline setting) is the
@@ -19,6 +19,7 @@ from repro.configs.deepseek_v3_671b import CONFIG as deepseek_v3_671b
 from repro.configs.smollm_135m import CONFIG as smollm_135m
 from repro.configs.internlm2_1_8b import CONFIG as internlm2_1_8b
 from repro.configs.granite_3_2b import CONFIG as granite_3_2b
+from repro.configs.granite_4_h_micro import CONFIG as granite_4_h_micro
 from repro.configs.qwen1_5_32b import CONFIG as qwen1_5_32b
 from repro.configs.mamba2_130m import CONFIG as mamba2_130m
 from repro.configs.whisper_base import CONFIG as whisper_base
@@ -32,6 +33,7 @@ ARCHS: dict[str, ArchConfig] = {
     "smollm-135m": smollm_135m,
     "internlm2-1.8b": internlm2_1_8b,
     "granite-3-2b": granite_3_2b,
+    "granite-4-h-micro": granite_4_h_micro,
     "qwen1.5-32b": qwen1_5_32b,
     "mamba2-130m": mamba2_130m,
     "whisper-base": whisper_base,

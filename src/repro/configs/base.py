@@ -80,6 +80,12 @@ class ArchConfig:
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
     embed_scale: bool = False         # gemma-style sqrt(d_model) embed scaling
+    # Granite-style scalars; the defaults add no operation to a program
+    embedding_multiplier: float = 1.0  # embeddings times this
+    attention_multiplier: float = 0.0  # score scale; 0 → 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0   # each block's branch times this
+    logits_scaling: float = 1.0        # logits divided by this
+    rms_norm_eps: float = 1e-6
 
     # per-layer mixer pattern, cycled over n_layers:
     #   'attn' | 'local_attn' | 'rglru' | 'ssd' | 'mla'
@@ -143,10 +149,12 @@ class ArchConfig:
 
     def reduced(self, **overrides) -> "ArchConfig":
         """Small same-family variant for CPU smoke tests."""
+        # two periods of a short layer pattern, one of a long one
+        period = len(self.pattern)
         small: dict = dict(
             vocab=min(self.vocab, 512),
             d_model=min(self.d_model, 64),
-            n_layers=min(self.n_layers, len(self.pattern) * 2),
+            n_layers=min(self.n_layers, period * (1 if period > 4 else 2)),
             d_ff=min(self.d_ff, 128) if self.d_ff else 0,
             param_dtype="float32",
             compute_dtype="float32",

@@ -197,9 +197,11 @@ def norm_init(d: int, kind: str, dtype) -> Params:
     return {"scale": jnp.ones((d,), dtype=dtype), "bias": jnp.zeros((d,), dtype=dtype)}
 
 
-def norm_apply(params: Params, x: jax.Array, kind: str) -> jax.Array:
+def norm_apply(params: Params, x: jax.Array, kind: str,
+               eps: float = 1e-6) -> jax.Array:
+    """``eps`` is RMSNorm's; LayerNorm keeps its own."""
     if kind == "rmsnorm":
-        return ops.rms_norm(x, params["scale"])
+        return ops.rms_norm(x, params["scale"], eps)
     return ops.layer_norm(x, params["scale"], params["bias"])
 
 
@@ -322,7 +324,8 @@ def attn_apply(spec: AttnSpec, params: Params, x: jax.Array,
     o = ops.chunked_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
         causal=causal, window=spec.window,
-        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+        scale=cfg.attention_multiplier or None)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, hq * hd)
     y = linear_apply(spec.out, params["out"], o)
     return parallel.shard_batch(y)
@@ -431,6 +434,7 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
     """
     cfg = spec.cfg
     hq, hkv, hd = spec.dims
+    scale = cfg.attention_multiplier or None
     B, C, _ = x.shape
     offs = jnp.arange(C, dtype=jnp.int32)
     q_pos = steps[:, None] + offs[None, :]           # (B, C)
@@ -442,7 +446,7 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
         o = ops.cache_attention(
             q, cache["k"], cache["v"], cache_layer(cache["pos"], layer),
             jnp.full((B, C), jnp.iinfo(jnp.int32).max // 2, jnp.int32),
-            layer=layer)
+            layer=layer, scale=scale)
         y = linear_apply(spec.out, params["out"], o.reshape(B, C, hq * hd))
         return parallel.shard_batch(y), cache
     if cfg.pos_embed == "rope":
@@ -506,14 +510,15 @@ def attn_prefill(spec: AttnSpec, params: Params, cache: Params, x: jax.Array,
                     v_old.dtype)], axis=1),
                 jnp.concatenate([cache_layer(cache["pos"], layer), kv_pos],
                                 axis=1),
-                q_pos, window=spec.window)
+                q_pos, window=spec.window, scale=scale)
         elif spec.cfg.cache_quant:
             k_cache, v_cache = floats(new_cache)
             o = ops.cache_attention(q, k_cache, v_cache, k_pos, q_pos,
-                                    window=spec.window)
+                                    window=spec.window, scale=scale)
         else:
             o = ops.cache_attention(q, new_cache["k"], new_cache["v"], k_pos,
-                                    q_pos, window=spec.window, layer=layer)
+                                    q_pos, window=spec.window, layer=layer,
+                                    scale=scale)
     y = linear_apply(spec.out, params["out"], o.reshape(B, C, hq * hd))
     return parallel.shard_batch(y), new_cache
 

@@ -85,16 +85,18 @@ def chunked_attention(
     q_offset: int = 0,
     q_chunk: int = 512,
     kv_chunk: int = 1024,
+    scale: float | None = None,
 ) -> jax.Array:
     """q: (B, Hq, T, Dk); k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv) → (B, Hq, T, Dv).
 
     Statically-unrolled q chunks; each contracts only its reachable KV slice
-    (causal upper bound / sliding-window lower bound, both static).
+    (causal upper bound / sliding-window lower bound, both static).  Scores
+    are scaled by ``scale``, by default 1/sqrt(Dk).
     """
     B, Hq, T, Dk = q.shape
     _, Hkv, S, Dv = k.shape[0], k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(Dk)
+    scale = 1.0 / math.sqrt(Dk) if scale is None else scale
     qg = q.reshape(B, Hkv, G, T, Dk).astype(jnp.float32)
     kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
 
@@ -167,6 +169,7 @@ def cache_attention(
     *,
     window: int | None = None,
     layer: jax.Array | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Multi-token decode/prefill attention over a (possibly ring) cache.
 
@@ -177,7 +180,8 @@ def cache_attention(
     (-1 = empty); q_pos: (B,) or (B, T) absolute position of each query
     row — per-slot offsets for continuous batching; T=1 is classic
     single-token decode, T=C a prefill chunk (intra-chunk causality falls
-    out of the position comparison).  Returns (B, T, Hq, Dv).
+    out of the position comparison).  Scores are scaled by ``scale``, by
+    default 1/sqrt(Dk).  Returns (B, T, Hq, Dv).
 
     The cache is read in the layout it is stored in: the heads are taken a
     lane group at a time (``lane_group``), and within a group each query
@@ -197,14 +201,15 @@ def cache_attention(
     if T > CACHE_Q_BLOCK:
         return jnp.concatenate([cache_attention(
             q[:, i:i + CACHE_Q_BLOCK], k_cache, v_cache, k_pos,
-            q_pos[:, i:i + CACHE_Q_BLOCK], window=window, layer=layer)
+            q_pos[:, i:i + CACHE_Q_BLOCK], window=window, layer=layer,
+            scale=scale)
             for i in range(0, T, CACHE_Q_BLOCK)], axis=1)
     Hkv = k_cache.shape[-1] // Dk
     Dv = v_cache.shape[-1] // Hkv
     G = Hq // Hkv
     P = lane_group(Hkv, Dk)
     S = k_cache.shape[-2]
-    scale = 1.0 / math.sqrt(Dk)
+    scale = 1.0 / math.sqrt(Dk) if scale is None else scale
     valid = (k_pos[:, None, :] >= 0) & (k_pos[:, None, :] <= q_pos[:, :, None])
     if window is not None:
         valid &= k_pos[:, None, :] > q_pos[:, :, None] - window

@@ -23,6 +23,7 @@ from repro import quant as qt
 from repro.configs.base import ArchConfig
 from repro.core.structures import LinearSpec, make_linear
 from repro.models import layers as L
+from repro.models.ops import causal_conv_chunk, rms_norm
 from repro.models.rglru import _conv1d
 from repro.parallel import Parallel, NO_PARALLEL
 
@@ -58,18 +59,26 @@ def make_ssd(cfg: ArchConfig) -> SSDSpec:
 
 
 def ssd_init(spec: SSDSpec, key, dtype) -> Params:
-    k1, k2, k3 = jax.random.split(key, 3)
-    H = spec.n_heads
+    """Mamba-2's initialiser: dt from log-uniform (1e-3, 1e-1) through
+    softplus⁻¹, A = -exp(A_log) from U(1, 16), D = 1, the depthwise conv's
+    taps and bias from U(±1/sqrt(K)) (a conv layer's default), the gated
+    norm's gain 1 (scale 0)."""
+    k1, k2, k3, k4, k5, k6 = jax.random.split(key, 6)
+    H, K = spec.n_heads, spec.conv_width
     conv_ch = spec.d_inner + 2 * spec.n_groups * spec.d_state
     dt = jnp.exp(jax.random.uniform(k3, (H,), minval=jnp.log(1e-3),
                                     maxval=jnp.log(1e-1)))
     dt_bias = dt + jnp.log(-jnp.expm1(-dt))  # softplus⁻¹(dt)
+    bound = 1.0 / K ** 0.5
     return {
         "in_proj": L.linear_init(spec.in_proj, k1, dtype),
         "out_proj": L.linear_init(spec.out_proj, k2, dtype),
-        "conv_w": jnp.zeros((spec.conv_width, conv_ch), dtype=dtype).at[-1].set(1.0),
-        "conv_b": jnp.zeros((conv_ch,), dtype=dtype),
-        "A_log": jnp.zeros((H,), jnp.float32),       # A = -exp(A_log) = -1
+        "conv_w": jax.random.uniform(k4, (K, conv_ch), minval=-bound,
+                                     maxval=bound).astype(dtype),
+        "conv_b": jax.random.uniform(k5, (conv_ch,), minval=-bound,
+                                     maxval=bound).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(k6, (H,), minval=1.0,
+                                            maxval=16.0)),
         "D": jnp.ones((H,), jnp.float32),
         "dt_bias": dt_bias.astype(jnp.float32),
         "norm": {"scale": jnp.zeros((spec.d_inner,), dtype=dtype)},
@@ -125,13 +134,18 @@ def _segsum(da: jax.Array) -> jax.Array:
 
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                 Cm: jax.Array, chunk: int, h0: jax.Array | None = None):
-    """Chunked SSD scan (fp32).
+    """Chunked SSD scan (fp32), from the state ``h0`` (zero if None).
 
-    x: (B, T, H, P); dt: (B, T, H); A: (H,); Bm/Cm: (B, T, G, N).
-    → y: (B, T, H, P), h_last: (B, H, P, N)
+    x: (B, T, H, P); dt: (B, T, H); A: (H,); Bm/Cm: (B, T, G, N);
+    h0: (B, H, P, N).  → y: (B, T, H, P), h_last: (B, H, P, N).
+
+    The H heads fall into G groups of R = H / G that share B and C, so the
+    scores C_l·B_s are computed once per group.  A step with dt = 0 leaves
+    the state exactly where it was (decay 1, input 0).
     """
     Bsz, T, H, Pd = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
+    R = H // G
     Ln = min(chunk, T)
     pad = (-T) % Ln
     if pad:
@@ -142,44 +156,42 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         x, dt, Bm, Cm = z(x), z(dt), z(Bm), z(Cm)
         T += pad
     nc = T // Ln
-    rep = H // G
-    xc = x.reshape(Bsz, nc, Ln, H, Pd)
-    dtc = dt.reshape(Bsz, nc, Ln, H)
-    Bc = jnp.repeat(Bm.reshape(Bsz, nc, Ln, G, N), rep, axis=3)   # (B,nc,L,H,N)
-    Cc = jnp.repeat(Cm.reshape(Bsz, nc, Ln, G, N), rep, axis=3)
-    da = dtc * A[None, None, None, :]                              # (B,nc,L,H)
-    xdt = xc * dtc[..., None]                                      # x̄ = dt·x
+    xdt = (x * dt[..., None]).reshape(Bsz, nc, Ln, G, R, Pd)      # x̄ = dt·x
+    da = (dt * A).reshape(Bsz, nc, Ln, G, R)
+    Bc = Bm.reshape(Bsz, nc, Ln, G, N)
+    Cc = Cm.reshape(Bsz, nc, Ln, G, N)
 
     # ---- intra-chunk (dual quadratic form)
-    Lmat = jnp.exp(_segsum(da.transpose(0, 1, 3, 2)))              # (B,nc,H,L,L)
-    scores = jnp.einsum("bclhn,bcshn->bchls", Cc, Bc)              # (B,nc,H,L,L)
-    y_intra = jnp.einsum("bchls,bchls,bcshp->bclhp", scores, Lmat, xdt)
+    Lmat = jnp.exp(_segsum(da.transpose(0, 1, 3, 4, 2)))           # (B,nc,G,R,L,L)
+    scores = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc)              # (B,nc,G,L,L)
+    y_intra = jnp.einsum("bcgls,bcgrls,bcsgrp->bclgrp", scores, Lmat, xdt)
 
     # ---- chunk states  S_c = Σ_l exp(Σ_{k>l} da) · B_l ⊗ x̄_l
-    da_cum = jnp.cumsum(da, axis=2)                                # (B,nc,L,H)
-    decay_states = jnp.exp(da_cum[:, :, -1:, :] - da_cum)          # (B,nc,L,H)
-    S = jnp.einsum("bclh,bclhn,bclhp->bchpn", decay_states, Bc, xdt)
+    da_cum = jnp.cumsum(da, axis=2)                                # (B,nc,L,G,R)
+    decay_states = jnp.exp(da_cum[:, :, -1:] - da_cum)
+    S = jnp.einsum("bclgr,bclgn,bclgrp->bcgrpn", decay_states, Bc, xdt)
 
     # ---- inter-chunk recurrence:  h_c = exp(Σ da_c)·h_{c-1} + S_c
-    chunk_decay = jnp.exp(da_cum[:, :, -1, :])                     # (B,nc,H)
+    chunk_decay = jnp.exp(da_cum[:, :, -1])                        # (B,nc,G,R)
 
     def step(h, inp):
         dec, s = inp
-        h_new = dec[:, :, None, None] * h + s
-        return h_new, h  # emit state *entering* the chunk
+        return dec[..., None, None] * h + s, h  # emit the state entering
 
-    h_init = jnp.zeros((Bsz, H, Pd, N), jnp.float32) if h0 is None else h0
+    h_init = (jnp.zeros((Bsz, G, R, Pd, N), jnp.float32) if h0 is None
+              else h0.reshape(Bsz, G, R, Pd, N))
     h_last, h_prev = jax.lax.scan(
-        step, h_init, (chunk_decay.transpose(1, 0, 2), S.transpose(1, 0, 2, 3, 4)))
-    h_prev = h_prev.transpose(1, 0, 2, 3, 4)                       # (B,nc,H,P,N)
+        step, h_init, (chunk_decay.transpose(1, 0, 2, 3),
+                       S.transpose(1, 0, 2, 3, 4, 5)))
+    h_prev = h_prev.transpose(1, 0, 2, 3, 4, 5)                    # (B,nc,G,R,P,N)
 
     # ---- inter-chunk output:  y_l += exp(da_cum_l) · C_l · h_prev
-    y_inter = jnp.einsum("bclh,bclhn,bchpn->bclhp",
+    y_inter = jnp.einsum("bclgr,bclgn,bcgrpn->bclgrp",
                          jnp.exp(da_cum), Cc, h_prev)
     y = (y_intra + y_inter).reshape(Bsz, T, H, Pd)
     if pad:
         y = y[:, : T - pad]
-    return y, h_last
+    return y, h_last.reshape(Bsz, H, Pd, N)
 
 
 def ssd_apply(spec: SSDSpec, params: Params, x: jax.Array,
@@ -191,19 +203,22 @@ def ssd_apply(spec: SSDSpec, params: Params, x: jax.Array,
     zxbcdt = L.linear_apply(spec.in_proj, params["in_proj"], x)
     zxbcdt = parallel.constraint(zxbcdt, parallel.batch_spec(None, None))
     z, xBC_pre, dt_raw = _split_in_proj(spec, zxbcdt)
-    xBC = jax.nn.silu(_conv1d(xBC_pre, params["conv_w"], params["conv_b"]))
+    with jax.named_scope("ssd_conv"):
+        xBC = jax.nn.silu(_conv1d(xBC_pre, params["conv_w"],
+                                  params["conv_b"]))
     xin, Bm, Cm = _split_xbc(spec, xBC)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"])
-    y, h_last = ssd_chunked(
-        xin.reshape(Bsz, T, H, Pd).astype(jnp.float32), dt, A,
-        Bm.reshape(Bsz, T, G, N).astype(jnp.float32),
-        Cm.reshape(Bsz, T, G, N).astype(jnp.float32), spec.chunk)
-    y = y + params["D"][None, None, :, None] * xin.reshape(
-        Bsz, T, H, Pd).astype(jnp.float32)
+    with jax.named_scope("ssd_scan"):
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"])
+        y, h_last = ssd_chunked(
+            xin.reshape(Bsz, T, H, Pd).astype(jnp.float32), dt, A,
+            Bm.reshape(Bsz, T, G, N).astype(jnp.float32),
+            Cm.reshape(Bsz, T, G, N).astype(jnp.float32), spec.chunk)
+        y = y + params["D"][None, None, :, None] * xin.reshape(
+            Bsz, T, H, Pd).astype(jnp.float32)
     y = y.reshape(Bsz, T, spec.d_inner).astype(x.dtype)
-    from repro.models.ops import rms_norm
-    y = rms_norm(y * jax.nn.silu(z), params["norm"]["scale"])
+    y = rms_norm(y * jax.nn.silu(z), params["norm"]["scale"],
+                 spec.cfg.rms_norm_eps)
     out = L.linear_apply(spec.out_proj, params["out_proj"], y)
     out = parallel.shard_batch(out)
     if not return_cache:
@@ -242,15 +257,19 @@ def ssd_prefill(spec: SSDSpec, params: Params, cache: Params, x: jax.Array,
                 steps: jax.Array, n_tokens: jax.Array,
                 parallel: Parallel = NO_PARALLEL, *,
                 collect: bool = False) -> tuple[jax.Array, Params]:
-    """Multi-token prefill: batched projections + exact per-token recurrence.
+    """Multi-token prefill from each row's cached state.
 
     The structured in/out projections — where the (tokens × rank) BLAST tiles
-    and hence the FLOPs live — run over the whole (B, C) chunk; the O(1)
-    state update runs in a lax.scan over C, bit-matching C sequential decode
-    steps.  Rows are ragged: column i of row b is live iff i < n_tokens[b];
-    dead columns neither advance (conv, h) nor contribute (their outputs are
-    garbage the engine discards).  ``steps`` is unused (no positional state)
-    but kept for the uniform mixer-prefill signature.
+    and hence the FLOPs live — run over the whole (B, C) chunk.  The state
+    math takes the chunked dual form (``ssd_chunked``, inner chunks of the
+    configuration's ``chunk``) from the cached state, reading and writing
+    the (B, H, P, N) state once per inner chunk; a decode step (C = 1) and
+    a speculative verify (``collect``) advance it token by token.  Rows are
+    ragged: column i of row b is live iff i < n_tokens[b]; dead columns get
+    dt = 0, so they neither advance (conv, h) nor contribute (their outputs
+    are garbage the engine discards), and a row with n_tokens = 0 keeps its
+    state exactly.  ``steps`` is unused (no positional state) but kept for
+    the uniform mixer-prefill signature.
 
     ``collect=True`` additionally returns per-token state snapshots in the
     cache (``h_snap (B, C+1, H, P, N)`` with index 0 = the incoming state,
@@ -260,54 +279,66 @@ def ssd_prefill(spec: SSDSpec, params: Params, cache: Params, x: jax.Array,
     del steps
     Bsz, C, _ = x.shape
     H, Pd, N, G = spec.n_heads, spec.head_dim, spec.d_state, spec.n_groups
-    rep = H // G
     conv_prev, h_prev = qt.unpack_state_cache(spec.cfg.cache_quant,
                                               cache, x.dtype)
     zxbcdt = L.linear_apply(spec.in_proj, params["in_proj"], x)
     z, xBC_pre, dt_raw = _split_in_proj(spec, zxbcdt)
     valid = jnp.arange(C)[None, :] < n_tokens[:, None]           # (B, C)
 
-    # Everything except the h recurrence is position-parallel and hoisted
-    # out of the scan.
-    from repro.models.ops import causal_conv_chunk
-    y_conv, conv_f = causal_conv_chunk(conv_prev, xBC_pre,
-                                       params["conv_w"], params["conv_b"],
-                                       n_tokens)
-    xBC = jax.nn.silu(y_conv)
+    with jax.named_scope("ssd_conv"):
+        y_conv, conv_f = causal_conv_chunk(conv_prev, xBC_pre,
+                                           params["conv_w"], params["conv_b"],
+                                           n_tokens)
+        xBC = jax.nn.silu(y_conv)
     xin, Bm, Cm = _split_xbc(spec, xBC)
-    xin = xin.reshape(Bsz, C, H, Pd).astype(jnp.float32)
-    Bm = jnp.repeat(Bm.reshape(Bsz, C, G, N).astype(jnp.float32), rep, axis=2)
-    Cm = jnp.repeat(Cm.reshape(Bsz, C, G, N).astype(jnp.float32), rep, axis=2)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
-    dt = jnp.where(valid[..., None], dt, 0.0)    # dead cols: a=1, x̄=0 → h fixed
-    a = jnp.exp(dt * (-jnp.exp(params["A_log"])))                # (B, C, H)
-
-    def tok(h, inp):
-        a_t, dt_t, Bm_t, Cm_t, xin_t = inp
-        h_new = (a_t[:, :, None, None] * h
-                 + jnp.einsum("bh,bhn,bhp->bhpn", dt_t, Bm_t, xin_t))
-        y_t = jnp.einsum("bhn,bhpn->bhp", Cm_t, h_new)
-        return h_new, ((y_t, h_new) if collect else y_t)
-
-    h_f, ys = jax.lax.scan(
-        tok, h_prev,
-        (a.transpose(1, 0, 2), dt.transpose(1, 0, 2),
-         Bm.transpose(1, 0, 2, 3), Cm.transpose(1, 0, 2, 3),
-         xin.transpose(1, 0, 2, 3)))
-    if collect:
-        ys, hs = ys
-    y = ys.transpose(1, 0, 2, 3) + params["D"][None, None, :, None] * xin
+    with jax.named_scope("ssd_scan"):
+        xin = xin.reshape(Bsz, C, H, Pd).astype(jnp.float32)
+        Bm = Bm.reshape(Bsz, C, G, N).astype(jnp.float32)
+        Cm = Cm.reshape(Bsz, C, G, N).astype(jnp.float32)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
+        dt = jnp.where(valid[..., None], dt, 0.0)  # dead: a=1, x̄=0 → h fixed
+        A = -jnp.exp(params["A_log"])
+        if C > 1 and not collect:
+            y, h_f = ssd_chunked(xin, dt, A, Bm, Cm, spec.chunk,
+                                 h0=h_prev.astype(jnp.float32))
+        else:
+            y, h_f, hs = _ssd_tokens(xin, dt, A, Bm, Cm, h_prev, collect)
+        y = y + params["D"][None, None, :, None] * xin
     y = y.reshape(Bsz, C, spec.d_inner).astype(x.dtype)
-    from repro.models.ops import rms_norm
-    y = rms_norm(y * jax.nn.silu(z), params["norm"]["scale"])
+    y = rms_norm(y * jax.nn.silu(z), params["norm"]["scale"],
+                 spec.cfg.rms_norm_eps)
     out = L.linear_apply(spec.out_proj, params["out_proj"], y)
     new_cache = qt.pack_state_cache(spec.cfg.cache_quant, conv_f, h_f)
     if collect:
         new_cache["h_snap"] = jnp.concatenate(
-            [h_prev.astype(jnp.float32)[:, None],
-             hs.transpose(1, 0, 2, 3, 4)], axis=1)     # (B, C+1, H, P, N)
+            [h_prev.astype(jnp.float32)[:, None], hs], axis=1)  # (B,C+1,H,P,N)
         new_cache["conv_hist"] = jnp.concatenate([conv_prev, xBC_pre], axis=1)
     return parallel.shard_batch(out), new_cache
+
+
+def _ssd_tokens(x, dt, A, Bm, Cm, h0, collect: bool):
+    """The SSD recurrence one token at a time: h ← exp(dt·A)·h + dt·B⊗x,
+    y = C·h.  Shapes as ``ssd_chunked``; also returns every token's state
+    (B, T, H, P, N) when ``collect``, else None."""
+    H, G = x.shape[2], Bm.shape[2]
+    Bm = jnp.repeat(Bm, H // G, axis=2)
+    Cm = jnp.repeat(Cm, H // G, axis=2)
+    a = jnp.exp(dt * A)                                          # (B, T, H)
+
+    def tok(h, inp):
+        a_t, dt_t, Bm_t, Cm_t, x_t = inp
+        h_new = (a_t[:, :, None, None] * h
+                 + jnp.einsum("bh,bhn,bhp->bhpn", dt_t, Bm_t, x_t))
+        y_t = jnp.einsum("bhn,bhpn->bhp", Cm_t, h_new)
+        return h_new, ((y_t, h_new) if collect else y_t)
+
+    t_major = lambda t: jnp.moveaxis(t, 1, 0)  # noqa: E731
+    h_f, ys = jax.lax.scan(tok, h0, tuple(map(t_major, (a, dt, Bm, Cm, x))))
+    hs = None
+    if collect:
+        ys, hs = ys
+        hs = jnp.moveaxis(hs, 0, 1)
+    return jnp.moveaxis(ys, 0, 1), h_f, hs
 
 
 def ssd_cache_rollback(spec: SSDSpec, cache: Params,

@@ -62,6 +62,12 @@ class BlockSpec:
     ffn_kind: str               # ffn | moe | none
     norm: str
     cross: L.AttnSpec | None = None
+    eps: float = 1e-6           # RMSNorm epsilon
+    residual: float = 1.0       # each branch is added times this
+
+    def add(self, x: jax.Array, branch: jax.Array) -> jax.Array:
+        """The residual add; a multiplier of 1 adds no operation."""
+        return x + (branch if self.residual == 1.0 else branch * self.residual)
 
 
 def make_block(cfg: ArchConfig, kind: str, *, moe_layer: bool = False,
@@ -89,7 +95,8 @@ def make_block(cfg: ArchConfig, kind: str, *, moe_layer: bool = False,
             ffn, ffn_kind = None, "none"
     xspec = L.make_attention(cfg, cross=True) if cross else None
     return BlockSpec(kind=kind, mixer=mixer, ffn=ffn, ffn_kind=ffn_kind,
-                     norm=cfg.norm, cross=xspec)
+                     norm=cfg.norm, cross=xspec, eps=cfg.rms_norm_eps,
+                     residual=cfg.residual_multiplier)
 
 
 def block_init(spec: BlockSpec, key, dtype, d_model: int) -> Params:
@@ -186,7 +193,7 @@ def block_linear_specs(spec: BlockSpec) -> list:
 def block_apply(spec: BlockSpec, params: Params, x: jax.Array,
                 positions: jax.Array, parallel: Parallel,
                 memory: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
-    h = L.norm_apply(params["norm1"], x, spec.norm)
+    h = L.norm_apply(params["norm1"], x, spec.norm, spec.eps)
     if spec.kind in ("attn", "local_attn"):
         m = L.attn_apply(spec.mixer, params["mixer"], h, positions, parallel)
     elif spec.kind == "mla":
@@ -195,19 +202,19 @@ def block_apply(spec: BlockSpec, params: Params, x: jax.Array,
         m = rglru_apply(spec.mixer, params["mixer"], h, positions, parallel)
     else:
         m = ssd_apply(spec.mixer, params["mixer"], h, positions, parallel)
-    x = x + m
+    x = spec.add(x, m)
     if spec.cross is not None:
-        h = L.norm_apply(params["norm_x"], x, spec.norm)
-        x = x + L.attn_apply(spec.cross, params["cross"], h, positions,
-                             parallel, memory=memory)
+        h = L.norm_apply(params["norm_x"], x, spec.norm, spec.eps)
+        x = spec.add(x, L.attn_apply(spec.cross, params["cross"], h,
+                                     positions, parallel, memory=memory))
     aux = jnp.zeros((), jnp.float32)
     if spec.ffn_kind == "moe":
-        h = L.norm_apply(params["norm2"], x, spec.norm)
+        h = L.norm_apply(params["norm2"], x, spec.norm, spec.eps)
         f, aux = moe_lib.moe_apply(spec.ffn, params["ffn"], h, parallel)
-        x = x + f
+        x = spec.add(x, f)
     elif spec.ffn_kind == "ffn":
-        h = L.norm_apply(params["norm2"], x, spec.norm)
-        x = x + L.ffn_apply(spec.ffn, params["ffn"], h, parallel)
+        h = L.norm_apply(params["norm2"], x, spec.norm, spec.eps)
+        x = spec.add(x, L.ffn_apply(spec.ffn, params["ffn"], h, parallel))
     # MaxText-style layer-boundary constraint: the residual stream re-enters
     # each block batch-sharded / d-replicated, so GSPMD never speculatively
     # leaves a TP partial-sum layout to flow across blocks
@@ -261,7 +268,7 @@ def block_prefill(spec: BlockSpec, params: Params, cache: Params, x: jax.Array,
     caches) are the model's stacked (L, …) leaves and ``layer`` is this
     block's index in them; they are written in place and returned stacked.
     The other leaves (recurrent state) are this layer's own."""
-    h = L.norm_apply(params["norm1"], x, spec.norm)
+    h = L.norm_apply(params["norm1"], x, spec.norm, spec.eps)
     new_cache = dict(cache)
     if spec.kind in ("attn", "local_attn"):
         m, new_cache["mixer"] = L.attn_prefill(
@@ -279,19 +286,19 @@ def block_prefill(spec: BlockSpec, params: Params, cache: Params, x: jax.Array,
         m, new_cache["mixer"] = ssd_prefill(
             spec.mixer, params["mixer"], cache["mixer"], h, steps, n_tokens,
             parallel, collect=collect)
-    x = x + m
+    x = spec.add(x, m)
     if spec.cross is not None:
-        h = L.norm_apply(params["norm_x"], x, spec.norm)
+        h = L.norm_apply(params["norm_x"], x, spec.norm, spec.eps)
         m, _ = L.attn_prefill(spec.cross, params["cross"], cache["cross"], h,
                               steps, n_tokens, parallel, layer=layer)
-        x = x + m
+        x = spec.add(x, m)
     if spec.ffn_kind == "moe":
-        h = L.norm_apply(params["norm2"], x, spec.norm)
+        h = L.norm_apply(params["norm2"], x, spec.norm, spec.eps)
         f, _ = moe_lib.moe_apply(spec.ffn, params["ffn"], h, parallel)
-        x = x + f
+        x = spec.add(x, f)
     elif spec.ffn_kind == "ffn":
-        h = L.norm_apply(params["norm2"], x, spec.norm)
-        x = x + L.ffn_apply(spec.ffn, params["ffn"], h, parallel)
+        h = L.norm_apply(params["norm2"], x, spec.norm, spec.eps)
+        x = spec.add(x, L.ffn_apply(spec.ffn, params["ffn"], h, parallel))
     return parallel.shard_batch(x), new_cache
 
 
@@ -465,8 +472,10 @@ class LM:
     def init(self, key: jax.Array) -> Params:
         cfg = self.cfg
         keys = jax.random.split(key, 8)
+        # a scaled embedding enters the first layer at 0.02; unscaled, a
+        # tied head makes a random-weight model repeat its input token
         params: Params = {
-            "embed": (0.02 * jax.random.normal(
+            "embed": (0.02 / cfg.embedding_multiplier * jax.random.normal(
                 keys[0], (cfg.vocab, cfg.d_model))).astype(self.dtype),
             "final_norm": L.norm_init(cfg.d_model, cfg.norm, self.dtype),
         }
@@ -658,15 +667,19 @@ class LM:
                            self.parallel)
         if self.cfg.embed_scale:
             x = x * jnp.sqrt(float(self.cfg.d_model)).astype(x.dtype)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * self.cfg.embedding_multiplier
         return x
 
     def _head(self, params: Params, x: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = L.norm_apply(params["final_norm"], x, cfg.norm)
+        x = L.norm_apply(params["final_norm"], x, cfg.norm, cfg.rms_norm_eps)
         if cfg.tie_embeddings:
             logits = L.tied_logits(params["embed"], x)
         else:
             logits = L.linear_apply(self.head, params["head"], x)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         logits = self.parallel.constraint(
             logits, self.parallel.batch_spec(None, self.parallel.model_axis))
         return ops.softcap(logits, cfg.logit_softcap)
@@ -722,7 +735,8 @@ class LM:
             # (h_t, embed(t+1)); lm_head shared.
             nxt = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
             h = jnp.concatenate(
-                [L.norm_apply(params["mtp"]["norm"], x, cfg.norm),
+                [L.norm_apply(params["mtp"]["norm"], x, cfg.norm,
+                              cfg.rms_norm_eps),
                  self._embed(params, nxt)], axis=-1)
             h = L.linear_apply(self.mtp_proj, params["mtp"]["proj"], h)
             h, _ = block_apply(self.mtp_spec, params["mtp"]["block"], h,

@@ -57,6 +57,7 @@ class TestDecodeEquivalence:
         "deepseek-v3-671b",       # MLA absorbed-latent decode + MoE
         "recurrentgemma-2b",      # RG-LRU + local attention ring buffer
         "mamba2-130m",            # SSD recurrent decode
+        "granite-4-h-micro",      # SSD + NoPE GQA, Granite's scalars
         "whisper-base",           # enc-dec with cross-attention cache
     ])
     def test_decode_matches_forward(self, arch):
@@ -203,3 +204,39 @@ class TestRGLRU:
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(h_last), np.asarray(h),
                                    rtol=1e-5, atol=1e-5)
+
+
+class TestGraniteScalars:
+    """Granite's multipliers and RMSNorm eps (``ArchConfig``): at their
+    defaults a program holds no operation for them, so Granite-3's serving
+    step is the one it was; a value that is set adds its own operation."""
+
+    @staticmethod
+    def _step(cfg) -> str:
+        model = build_model(cfg)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        cache = jax.eval_shape(lambda: model.init_cache(2, 16))
+        tokens = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+        steps = jax.ShapeDtypeStruct((2,), jnp.int32)
+        return str(jax.make_jaxpr(model.prefill_chunk)(
+            params, cache, tokens, steps, steps))
+
+    def test_defaults_add_no_operation(self):
+        base = configs.get("granite-3-2b").reduced()
+        step = self._step(base)
+        spelled = dataclasses.replace(
+            base, attention_multiplier=base.head_dim_ ** -0.5,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            logits_scaling=1.0, rms_norm_eps=1e-6)
+        assert self._step(spelled) == step
+        count = lambda text, op: text.count(f"= {op} ")  # noqa: E731
+        # one embedding, two residual adds in the layer loop's one block,
+        # one logits division
+        for field, value, op, added in [
+                ("embedding_multiplier", 12.0, "mul", 1),
+                ("residual_multiplier", 0.22, "mul", 2),
+                ("logits_scaling", 8.0, "div", 1),
+                ("attention_multiplier", 1 / 64, "mul", 0)]:
+            text = self._step(dataclasses.replace(base, **{field: value}))
+            assert text != step, field
+            assert count(text, op) - count(step, op) == added, field

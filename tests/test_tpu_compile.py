@@ -218,3 +218,42 @@ def test_granite_step_moves_no_whole_layer_of_cache(one_chip, structure, C):
     logits_bytes = B * cfg.vocab * jnp.dtype(cfg.compute_dtype).itemsize
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes <= cache_bytes + 2 * logits_bytes, mem
+
+
+@pytest.mark.parametrize("C", [1, 256], ids=["decode", "prefill"])
+def test_granite4_hybrid_step_fits_one_chip(one_chip, C):
+    """The serving step of the benchmark's Granite-4.0-H-Micro BLAST
+    configuration (32 slots, max_len 4096, pre-stacked params, bf16)
+    compiled for a v5e: no copy, transpose or dynamic-update-slice inside
+    the layer loop makes a whole layer of K or V, and the step with its
+    weights, both caches and its temporaries fits the chip's 16 GB."""
+    cfg = dataclasses.replace(configs.get("granite-4-h-micro"),
+                              param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    model = build_model(cfg)
+    B, S = 32, 4096
+    HD = cfg.n_kv_heads * cfg.head_dim_
+    L = cfg.layer_kinds().count("attn")
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = place(jax.eval_shape(
+        lambda k: model.prestack_params(model.init(k)),
+        jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(B, S)))
+    tokens = _sds((B, C), jnp.int32, one_chip)
+    steps = _sds((B,), jnp.int32, one_chip)
+    compiled = jax.jit(model.prefill_chunk).lower(
+        params, cache, tokens, steps, steps).compile()
+
+    def kv(shape):
+        return (math.prod(shape) >= B * S * HD and shape[-1:] == (HD,)
+                and bool({S, B * S, L * B * S} & set(shape)))
+
+    in_loop = [m for m in _data_moves(compiled.as_text())
+               if kv(m[2]) and not m[0]]
+    assert not in_loop, in_loop
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    mem = compiled.memory_analysis()
+    assert (weights + mem.output_size_in_bytes + mem.temp_size_in_bytes
+            + sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+            < 16e9), mem
